@@ -67,6 +67,7 @@ def _worker(cell: str, n_devices: int, model_shards: int, dtype: str,
     import jax.numpy as jnp
     import numpy as np
 
+    from benchmarks.common import device_info
     from repro.core import init_state, make_compressor, make_hyper
     from repro.data import TokenStream
     from repro.launch.mesh import make_train_mesh
@@ -127,7 +128,7 @@ def _worker(cell: str, n_devices: int, model_shards: int, dtype: str,
         "n_devices": n_devices, "model_shards": model_shards,
         "dtype": dtype, "local_steps": local_steps,
         "n_clients": N_CLIENTS, "batch": BATCH, "seq": SEQ, "steps": STEPS,
-        "n_local": n_local, "n_agg": n_agg,
+        "n_local": n_local, "n_agg": n_agg, "device": device_info(),
     }), flush=True)
 
 
@@ -144,7 +145,6 @@ def run() -> None:
                     "--xla_force_host_platform_device_count")]
         env["XLA_FLAGS"] = " ".join(
             kept + [f"--xla_force_host_platform_device_count={ndev}"])
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in [os.path.join(_ROOT, "src"), _ROOT,
                         env.get("PYTHONPATH", "")] if p)
@@ -160,7 +160,8 @@ def run() -> None:
         common.emit(
             f"lm_tokens_per_s_{cell}", row.pop("us_per_call"),
             f"tokens/s={row['tokens_per_sec']:.0f} shards={shards} "
-            f"dtype={dtype} H={h} agg={row['n_agg']}", **row)
+            f"dtype={dtype} H={h} agg={row['n_agg']}",
+            device=row.pop("device"), **row)
     base = rows[BASELINE]["tokens_per_sec"]
     head = rows[HEADLINE]["tokens_per_sec"]
     if head <= base:

@@ -48,6 +48,7 @@ def _worker(n_devices: int, participation: float) -> None:
     import jax.numpy as jnp
     import numpy as np
 
+    from benchmarks.common import device_info
     from repro.core import init_state, make_compressor, make_hyper
     from repro.core.rollout import rollout_l2gd, rollout_l2gd_sharded
     from repro.launch.mesh import make_client_mesh
@@ -97,7 +98,7 @@ def _worker(n_devices: int, participation: float) -> None:
         "us_per_step": round(dt * 1e6 / STEPS, 1),
         "n_devices": n_devices, "participation": participation,
         "n_clients": N_CLIENTS, "dim": DIM, "steps": STEPS,
-        "n_agg_comm": int(trace.n_agg_comm),
+        "n_agg_comm": int(trace.n_agg_comm), "device": device_info(),
     }), flush=True)
 
 
@@ -115,7 +116,6 @@ def run() -> None:
                         "--xla_force_host_platform_device_count")]
             env["XLA_FLAGS"] = " ".join(
                 kept + [f"--xla_force_host_platform_device_count={ndev}"])
-            env.setdefault("JAX_PLATFORMS", "cpu")
             env["PYTHONPATH"] = os.pathsep.join(
                 p for p in [os.path.join(_ROOT, "src"), _ROOT,
                             env.get("PYTHONPATH", "")] if p)
@@ -131,7 +131,8 @@ def run() -> None:
                 f"sharded_rollout_d{ndev}_p{part}", row.pop("us_per_call"),
                 f"clients/s={row['clients_per_sec']:.0f} "
                 f"devices={ndev} participation={part} "
-                f"agg_comm={row['n_agg_comm']}", **row)
+                f"agg_comm={row['n_agg_comm']}",
+                device=row.pop("device"), **row)
     common.merge_json(_JSON, common.RESULTS[start:])
 
 

@@ -53,7 +53,20 @@ def scenario_name(prefix: str, *parts) -> str:
     return "_".join(segs)
 
 
-def emit(name: str, us_per_call: float, derived, **extra) -> None:
+def device_info() -> dict:
+    """The devices this process runs on, as JAX reports them — the
+    ``device`` record of every row it emits."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
+def emit(name: str, us_per_call: float, derived, *, device: dict = None,
+         **extra) -> None:
+    """Print and record one row.  ``device`` is the record of the
+    process that measured it — a worker subprocess passes its own, so a
+    parent that only spawns workers never starts a JAX backend; by
+    default it is this process's :func:`device_info`."""
     if any(r["name"] == name for r in RESULTS):
         print(f"[warn] duplicate bench row name {name!r}: this row will "
               "shadow the earlier one in the --check baseline; add the "
@@ -62,7 +75,7 @@ def emit(name: str, us_per_call: float, derived, **extra) -> None:
     print(f"{name},{us_per_call:.1f},{derived}", flush=True)
     RESULTS.append({"name": name, "us_per_call": round(us_per_call, 1),
                     "derived": str(derived),
-                    "backend": jax.default_backend(), **extra})
+                    "device": device or device_info(), **extra})
 
 
 def write_json(path: str, results=None) -> None:

@@ -229,7 +229,7 @@ def _async_agg_fresh(st, agg, k, part, lat, drp, crs, *, n, q, grad_fn, hp,
         cohort_batches = fleet_encode(fleet, client_keys, st.params)
         fin = fleet_finite_mask(cohort_batches, n)
     elif fused:
-        payload = jax.vmap(up_plan.encode)(client_keys, st.params)
+        payload = flatbuf.encode_clients(up_plan, client_keys, st.params)
         fin = flatbuf.payload_finite_mask(payload)
         payload = flatbuf.sanitize_payload(payload, fin)
     else:

@@ -122,7 +122,10 @@ def pack_bits(fields: jax.Array, width: int) -> jax.Array:
     shifted fields and their byte sum are exact in 8 bits (all-ones at
     width 1 sums to exactly 255), so the intermediates carry 1 byte per
     field instead of the 4 of a uint32 pipeline — this pack is on the
-    wire-encode hot path (``pack_tree_natural``, ``TernGrad.encode``)."""
+    wire-encode hot path (``pack_tree_natural``, ``TernGrad.encode``).
+    A 1-D buffer takes the lane-dense :func:`_pack_bits_lanes`."""
+    if fields.ndim == 1:
+        return _pack_bits_lanes(fields, width)
     per = 8 // width
     if 8 % width == 0:
         b = fields.astype(jnp.uint8).reshape(fields.shape[:-1] + (-1, per))
@@ -136,7 +139,10 @@ def pack_bits(fields: jax.Array, width: int) -> jax.Array:
 def unpack_bits(packed: jax.Array, width: int) -> jax.Array:
     """Inverse of :func:`pack_bits` (returns uint32 fields).  Widths
     dividing 8 shift/mask in uint8 (4x narrower intermediates than the
-    generic uint32 path); the final widening cast fuses into consumers."""
+    generic uint32 path); the final widening cast fuses into consumers.
+    A 1-D buffer takes the lane-dense :func:`_unpack_bits_lanes`."""
+    if packed.ndim == 1:
+        return _unpack_bits_lanes(packed, width)
     per = 8 // width
     if 8 % width == 0:
         shifts = jnp.arange(per, dtype=jnp.uint8) * jnp.uint8(width)
@@ -147,6 +153,59 @@ def unpack_bits(packed: jax.Array, width: int) -> jax.Array:
     mask = jnp.uint32((1 << width) - 1)
     out = (packed.astype(jnp.uint32)[..., None] >> shifts) & mask
     return out.reshape(packed.shape[:-1] + (-1,))
+
+
+# The forms of pack_bits / unpack_bits for 1-D buffers (what the
+# leafwise codecs pack), on every backend.  The shift forms above view
+# the fields as (bytes, 8 // width), a minor dimension of 8 or fewer,
+# which a TPU pads to 128 lanes: 16x the bytes, and a program that packs
+# and unpacks (the client-sharded engine's encode -> all_gather ->
+# decode) kept the TPU compiler busy for minutes at stablelm-1.6b
+# widths.  Here the buffer is cut into rows of 128 bytes and the
+# per-byte shifts become a matmul with power-of-two weights, so every
+# minor dimension is 128 bytes or 128 * per fields.  It is exact: fields
+# below 2**width and byte values up to 255 are exact in bf16, and the
+# matmul sums them in f32.  On the CPU a pack and unpack of 2^24 fields
+# takes about 1.6x the shift form's time.  2-D buffers (the flat
+# transport's (buckets, 128) rows, already 128 lanes wide) keep the
+# shift form: packed lane-dense, the flat encode of two stablelm-1.6b
+# clients took the TPU compiler over four minutes.
+_LANES = 128
+
+
+def _lane_rows(flat: jax.Array, row: int) -> jax.Array:
+    """The 1-D buffer as (rows, ``row``), zero-padded at the end."""
+    pad = (-flat.shape[0]) % row
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
+    return flat.reshape(-1, row)
+
+
+def _pack_bits_lanes(fields: jax.Array, width: int) -> jax.Array:
+    per = 8 // width
+    weights = np.zeros((_LANES * per, _LANES), np.float32)
+    for k in range(per):
+        weights[np.arange(_LANES) * per + k, np.arange(_LANES)] = \
+            2.0 ** (width * k)
+    rows = _lane_rows(fields.astype(jnp.bfloat16), _LANES * per)
+    packed = jnp.dot(rows, jnp.asarray(weights, jnp.bfloat16),
+                     preferred_element_type=jnp.float32)
+    packed = packed.astype(jnp.int32).astype(jnp.uint8).reshape(-1)
+    return packed[:fields.shape[0] // per]
+
+
+def _unpack_bits_lanes(packed: jax.Array, width: int) -> jax.Array:
+    per = 8 // width
+    spread = np.zeros((_LANES, _LANES * per), np.float32)
+    spread[np.arange(_LANES * per) // per, np.arange(_LANES * per)] = 1.0
+    rows = _lane_rows(packed.astype(jnp.bfloat16), _LANES)
+    # every byte repeated per times along the lanes, then field k of it
+    # shifted down where lane % per == k
+    byte = jnp.dot(rows, jnp.asarray(spread, jnp.bfloat16),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, byte.shape, 1)
+    fields = (byte >> ((lane % per) * width)) & ((1 << width) - 1)
+    return fields.astype(jnp.uint32).reshape(-1)[:packed.shape[0] * per]
 
 
 def natural_split(y: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -306,7 +306,7 @@ def fleet_encode(fleet: FleetPlan, client_keys, params_stacked):
         keys_c = client_keys[ia]
         sub = jax.tree_util.tree_map(lambda a: a[ia], params_stacked)
         if plan.transport in ("flat", "packed"):
-            payload = jax.vmap(plan.encode)(keys_c, sub)
+            payload = flatbuf.encode_clients(plan, keys_c, sub)
             fin = flatbuf.payload_finite_mask(payload)
             payload = flatbuf.sanitize_payload(payload, fin)
             batches.append(CohortBatch(c, idx, "fused", payload, fin))

@@ -18,9 +18,11 @@ and routes CPU traffic to the jnp fallback (``on_tpu()``).
 from __future__ import annotations
 
 import jax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["on_tpu", "default_interpret", "autotune_rows",
-           "autotune_attn_blocks"]
+           "autotune_attn_blocks", "row_align", "scalar_spec"]
 
 # Working VMEM budget for one pipeline stage.  Cores have ~16 MiB of VMEM;
 # we target a quarter of it so double buffering (x2) plus compiler scratch
@@ -39,16 +41,37 @@ def default_interpret() -> bool:
     return not on_tpu()
 
 
-def autotune_rows(n_buckets: int, bucket: int, *, n_buffers: int = 3,
-                  itemsize: int = 4,
+def row_align(itemsize: int) -> int:
+    """Rows of one native (sublane, 128) TPU tile for ``itemsize``-byte
+    data: 8 for 32-bit, 16 for 16-bit, 32 for 8-bit (narrow dtypes pack
+    several rows per 32-bit sublane)."""
+    return _ROW_ALIGN * max(4 // int(itemsize), 1)
+
+
+def autotune_rows(n_buckets: int, row_bytes: int, *, min_itemsize: int = 4,
                   vmem_budget: int = _VMEM_BUDGET_BYTES) -> int:
-    """Rows (buckets) per grid step so ``n_buffers`` live (rows, bucket)
-    tiles fit in the VMEM budget, sublane-aligned and clamped to the grid.
-    """
-    bytes_per_row = max(n_buffers * bucket * itemsize, 1)
-    rows = vmem_budget // bytes_per_row
-    rows = (rows // _ROW_ALIGN) * _ROW_ALIGN
-    return int(min(max(rows, 1), max(n_buckets, 1)))
+    """Rows (buckets) per grid step so the kernel's live VMEM bytes,
+    ``row_bytes`` per bucket row, fit the budget, clamped to the grid.
+    Rows are aligned to the native tile of the narrowest dtype the
+    kernel blocks (``min_itemsize`` bytes): 32 rows for int8/uint8."""
+    align = row_align(min_itemsize)
+    rows = vmem_budget // max(int(row_bytes), 1)
+    rows = max((rows // align) * align, align)
+    return int(min(rows, max(n_buckets, 1)))
+
+
+def scalar_spec(shape, interpret: bool):
+    """Block spec for a small whole-array operand read as scalars (the
+    RNG seed pair, the (n,) per-client weights): SMEM when compiled —
+    a VMEM block of it would break the (8, 128) tiling rule — and one
+    whole-array block under the interpreter.  Pass the seed pair as a
+    (1, 2) array: a vmapped kernel squeezes the new leading axis out of
+    the block, and the tiling rule, which SMEM blocks obey too, then
+    still sees the full last two dims."""
+    if interpret:
+        zeros = (0,) * len(shape)
+        return pl.BlockSpec(tuple(shape), lambda *_: zeros)
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 _ATTN_BLOCK_ALIGN = 128  # MXU tile edge; q/k blocks stay lane-aligned

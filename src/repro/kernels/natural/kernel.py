@@ -20,12 +20,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import autotune_rows, default_interpret, on_tpu
+from repro.kernels.dispatch import (autotune_rows, default_interpret, on_tpu,
+                                   scalar_spec)
 from repro.kernels.natural.ref import (natural_compress_ref,
                                        natural_fused_ref, natural_pack_ref)
-from repro.kernels.rng import bits_to_uniform, counter_bits
+from repro.kernels.rng import tile_uniform
 
 __all__ = ["natural_compress_2d", "natural_fused", "natural_fused_pallas",
            "natural_pack"]
@@ -34,7 +34,9 @@ __all__ = ["natural_compress_2d", "natural_fused", "natural_fused_pallas",
 def _round_to_pow2(x, u):
     bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
     mantissa = bits & jnp.uint32(0x7FFFFF)
-    prob = mantissa.astype(jnp.float32) * (1.0 / float(1 << 23))
+    # mantissa < 2^23: exact through int32 (Mosaic has no u32 -> f32 cast)
+    prob = mantissa.astype(jnp.int32).astype(jnp.float32) \
+        * (1.0 / float(1 << 23))
     up = (u < prob).astype(jnp.uint32)
     rounded = (bits & jnp.uint32(0xFF800000)) + (up << 23)
     out = jax.lax.bitcast_convert_type(rounded, jnp.float32)
@@ -54,7 +56,7 @@ def natural_compress_2d(x2d: jax.Array, noise: jax.Array, *, rows: int = None,
     if interpret is None:
         interpret = default_interpret()
     if rows is None:
-        rows = autotune_rows(n, b, n_buffers=3)
+        rows = autotune_rows(n, 3 * b * 4)
     rows = min(rows, n)
     return pl.pallas_call(
         _natural_kernel,
@@ -69,18 +71,7 @@ def natural_compress_2d(x2d: jax.Array, noise: jax.Array, *, rows: int = None,
 
 def _natural_fused_kernel(seeds_ref, x_ref, o_ref, *, hw_rng: bool):
     x = x_ref[...].astype(jnp.float32)
-    if hw_rng:
-        pltpu.prng_seed(seeds_ref[0], seeds_ref[1], pl.program_id(0))
-        bits = pltpu.prng_random_bits(x.shape)
-        if bits.dtype != jnp.uint32:
-            bits = jax.lax.bitcast_convert_type(bits, jnp.uint32)
-        u = bits_to_uniform(bits)
-    else:
-        row0 = (pl.program_id(0) * x.shape[0]).astype(jnp.uint32)
-        r = jax.lax.broadcasted_iota(jnp.uint32, x.shape, 0)
-        c = jax.lax.broadcasted_iota(jnp.uint32, x.shape, 1)
-        idx = (row0 + r) * jnp.uint32(x.shape[1]) + c
-        u = bits_to_uniform(counter_bits(idx, seeds_ref[0], seeds_ref[1]))
+    u = tile_uniform(seeds_ref, x.shape, hw_rng)
     o_ref[...] = _round_to_pow2(x, u).astype(o_ref.dtype)
 
 
@@ -96,18 +87,17 @@ def natural_fused_pallas(x2d: jax.Array, seeds: jax.Array, *,
     if hw_rng is None:
         hw_rng = not interpret
     if rows is None:
-        rows = autotune_rows(n, b, n_buffers=2)
+        rows = autotune_rows(n, 2 * b * 4)
     rows = min(rows, n)
-    seed_spec = (pl.BlockSpec(seeds.shape, lambda i: (0,)) if interpret
-                 else pl.BlockSpec(memory_space=pltpu.SMEM))
     return pl.pallas_call(
         functools.partial(_natural_fused_kernel, hw_rng=hw_rng),
         grid=(pl.cdiv(n, rows),),
-        in_specs=[seed_spec, pl.BlockSpec((rows, b), lambda i: (i, 0))],
+        in_specs=[scalar_spec((1, 2), interpret),
+                  pl.BlockSpec((rows, b), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, b), x2d.dtype),
         interpret=interpret,
-    )(seeds, x2d)
+    )(seeds.reshape(1, 2), x2d)
 
 
 _natural_fused_jnp = jax.jit(natural_fused_ref)

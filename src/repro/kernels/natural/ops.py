@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import autotune_rows, on_tpu
+from repro.kernels.dispatch import autotune_rows, on_tpu, scalar_spec
 from repro.kernels.natural.kernel import natural_fused, natural_fused_pallas
 from repro.kernels.natural.ref import natural_reduce_ref
 
@@ -74,7 +74,7 @@ def _natural_reduce_kernel(*refs, has_w: bool):
 
     y = _merge_tile(e_ref, s_ref)
     if has_w:
-        y = y * w_ref[0, 0]
+        y = y * w_ref[i]
     acc_ref[...] += y
 
     @pl.when(i == pl.num_programs(1) - 1)
@@ -96,8 +96,8 @@ def _natural_reduce_pallas(exps, signs, weights, *, rows: int,
     args = (exps, signs)
     kernel = functools.partial(_natural_reduce_kernel, has_w=has_w)
     if has_w:
-        in_specs.append(pl.BlockSpec((1, 1), lambda t, i: (i, 0)))
-        args = args + (weights.reshape(n, 1),)
+        in_specs.append(scalar_spec((n,), interpret))
+        args = args + (weights.reshape(n).astype(jnp.float32),)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -109,6 +109,19 @@ def _natural_reduce_pallas(exps, signs, weights, *, rows: int,
     )(*args)
 
 
+def _reduce_row_bytes(b: int) -> int:
+    """VMEM bytes one bucket row of the natural reduce keeps live.  A
+    VMEM tile pads its minor dim to 128 lanes, which the in-tile sign
+    unpack pays for: its (b // 8, 8) uint32 expansion occupies
+    (b // 8, 128) words per row, 16x the data it holds."""
+    lanes = lambda m: -(-m // _LANE) * _LANE
+    io = 2 * b + 2 * lanes(b // 8) + 2 * 4 * b   # double-buffered blocks
+    acc = 4 * b                                   # f32 scratch
+    unpack = 4 * lanes(b // 8) + 4 * (b // 8) * _LANE
+    merge = 4 * 4 * b                # exps u32, signs u32, bits, y
+    return io + acc + unpack + merge
+
+
 def natural_reduce_pallas(exps, signs, weights=None, *, rows: int = None,
                           interpret: bool = None):
     """Pallas path of :func:`natural_reduce`: grid (bucket_tiles, n) with
@@ -118,7 +131,7 @@ def natural_reduce_pallas(exps, signs, weights=None, *, rows: int = None,
     if interpret is None:
         interpret = not on_tpu()
     if rows is None:
-        rows = autotune_rows(nb, b, n_buffers=3)
+        rows = autotune_rows(nb, _reduce_row_bytes(b), min_itemsize=1)
     return _natural_reduce_pallas(exps, signs, weights, rows=rows,
                                   interpret=interpret,
                                   has_w=weights is not None)

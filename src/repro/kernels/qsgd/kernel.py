@@ -34,32 +34,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import autotune_rows, default_interpret, on_tpu
+from repro.kernels.dispatch import (autotune_rows, default_interpret, on_tpu,
+                                   scalar_spec)
 from repro.kernels.qsgd.ref import (qsgd_dequantized_ref, qsgd_fused_ref,
                                     qsgd_pack_ref, qsgd_unpack_ref)
-from repro.kernels.rng import bits_to_uniform, counter_bits
+from repro.kernels.rng import tile_uniform
 
 __all__ = ["qsgd_dequantized", "qsgd_fused", "qsgd_fused_pallas",
            "qsgd_pack", "qsgd_pack_pallas", "qsgd_unpack",
            "qsgd_unpack_pallas"]
-
-
-def _tile_uniform(seeds_ref, shape, hw_rng: bool):
-    """[0,1) uniform tile; hardware PRNG on compiled TPU, counter RNG
-    (bit-compatible with the jnp fallback and ref oracles) otherwise."""
-    if hw_rng:
-        pltpu.prng_seed(seeds_ref[0], seeds_ref[1], pl.program_id(0))
-        bits = pltpu.prng_random_bits(shape)
-        if bits.dtype != jnp.uint32:
-            bits = jax.lax.bitcast_convert_type(bits, jnp.uint32)
-        return bits_to_uniform(bits)
-    row0 = (pl.program_id(0) * shape[0]).astype(jnp.uint32)
-    r = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
-    c = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
-    idx = (row0 + r) * jnp.uint32(shape[1]) + c
-    return bits_to_uniform(counter_bits(idx, seeds_ref[0], seeds_ref[1]))
 
 
 def _quantize(x, u, levels: int):
@@ -71,12 +55,6 @@ def _quantize(x, u, levels: int):
     lo = jnp.floor(scaled)
     q = lo + (u < (scaled - lo)).astype(jnp.float32)
     return jnp.sign(x) * q, norm
-
-
-def _seed_spec(seeds, interpret: bool):
-    if interpret:
-        return pl.BlockSpec(seeds.shape, lambda i: (0,))
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # --------------------------------------------------------------------------
@@ -99,7 +77,7 @@ def qsgd_dequantized(x2d: jax.Array, noise: jax.Array, *, levels: int = 127,
     if interpret is None:
         interpret = default_interpret()
     if rows is None:
-        rows = autotune_rows(n, b, n_buffers=3)
+        rows = autotune_rows(n, 3 * b * 4)
     rows = min(rows, n)
     grid = (pl.cdiv(n, rows),)
     return pl.pallas_call(
@@ -121,7 +99,7 @@ def qsgd_dequantized(x2d: jax.Array, noise: jax.Array, *, levels: int = 127,
 
 def _qsgd_fused_kernel(seeds_ref, x_ref, o_ref, *, levels: int, hw_rng: bool):
     x = x_ref[...].astype(jnp.float32)
-    u = _tile_uniform(seeds_ref, x.shape, hw_rng)
+    u = tile_uniform(seeds_ref, x.shape, hw_rng)
     codes, norm = _quantize(x, u, levels)
     out = codes * (norm / float(levels))
     o_ref[...] = jnp.where(norm == 0.0, 0.0, out).astype(o_ref.dtype)
@@ -140,19 +118,19 @@ def qsgd_fused_pallas(x2d: jax.Array, seeds: jax.Array, *, levels: int = 127,
     if hw_rng is None:
         hw_rng = not interpret
     if rows is None:
-        rows = autotune_rows(n, b, n_buffers=2)
+        rows = autotune_rows(n, 2 * b * 4)
     rows = min(rows, n)
     return pl.pallas_call(
         functools.partial(_qsgd_fused_kernel, levels=levels, hw_rng=hw_rng),
         grid=(pl.cdiv(n, rows),),
         in_specs=[
-            _seed_spec(seeds, interpret),
+            scalar_spec((1, 2), interpret),
             pl.BlockSpec((rows, b), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((rows, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, b), x2d.dtype),
         interpret=interpret,
-    )(seeds, x2d)
+    )(seeds.reshape(1, 2), x2d)
 
 
 _qsgd_fused_jnp = jax.jit(qsgd_fused_ref, static_argnames=("levels",))
@@ -176,7 +154,7 @@ def qsgd_fused(x2d: jax.Array, seeds: jax.Array, *,
 def _qsgd_pack_kernel(seeds_ref, x_ref, c_ref, n_ref, *, levels: int,
                       hw_rng: bool):
     x = x_ref[...].astype(jnp.float32)
-    u = _tile_uniform(seeds_ref, x.shape, hw_rng)
+    u = tile_uniform(seeds_ref, x.shape, hw_rng)
     codes, norm = _quantize(x, u, levels)
     c_ref[...] = codes.astype(jnp.int8)     # |codes| <= levels <= 127
     n_ref[...] = norm
@@ -197,13 +175,13 @@ def qsgd_pack_pallas(x2d: jax.Array, seeds: jax.Array, *, levels: int = 127,
     if hw_rng is None:
         hw_rng = not interpret
     if rows is None:
-        rows = autotune_rows(n, b, n_buffers=2)
+        rows = autotune_rows(n, 2 * b * 4, min_itemsize=1)
     rows = min(rows, n)
     return pl.pallas_call(
         functools.partial(_qsgd_pack_kernel, levels=levels, hw_rng=hw_rng),
         grid=(pl.cdiv(n, rows),),
         in_specs=[
-            _seed_spec(seeds, interpret),
+            scalar_spec((1, 2), interpret),
             pl.BlockSpec((rows, b), lambda i: (i, 0)),
         ],
         out_specs=[
@@ -215,7 +193,7 @@ def qsgd_pack_pallas(x2d: jax.Array, seeds: jax.Array, *, levels: int = 127,
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(seeds, x2d)
+    )(seeds.reshape(1, 2), x2d)
 
 
 _qsgd_pack_jnp = jax.jit(qsgd_pack_ref, static_argnames=("levels",))
@@ -242,7 +220,7 @@ def qsgd_unpack_pallas(codes: jax.Array, norms: jax.Array, *,
     if interpret is None:
         interpret = default_interpret()
     if rows is None:
-        rows = autotune_rows(n, b, n_buffers=2)
+        rows = autotune_rows(n, 2 * b * 4, min_itemsize=1)
     rows = min(rows, n)
     return pl.pallas_call(
         functools.partial(_qsgd_unpack_kernel, levels=levels),

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import autotune_rows, on_tpu
+from repro.kernels.dispatch import autotune_rows, on_tpu, scalar_spec
 from repro.kernels.qsgd.kernel import qsgd_fused, qsgd_fused_pallas
 from repro.kernels.qsgd.ref import qsgd_reduce_ref
 
@@ -65,7 +65,7 @@ def _qsgd_reduce_kernel(*refs, levels: int, has_w: bool):
 
     y = c_ref[0].astype(jnp.float32) * (n_ref[0] / float(levels))
     if has_w:
-        y = y * w_ref[0, 0]
+        y = y * w_ref[i]
     acc_ref[...] += y
 
     @pl.when(i == pl.num_programs(1) - 1)
@@ -88,8 +88,8 @@ def _qsgd_reduce_pallas(codes, norms, weights, *, levels: int, rows: int,
     kernel = functools.partial(_qsgd_reduce_kernel, levels=levels,
                                has_w=has_w)
     if has_w:
-        in_specs.append(pl.BlockSpec((1, 1), lambda t, i: (i, 0)))
-        args = args + (weights.reshape(n, 1),)
+        in_specs.append(scalar_spec((n,), interpret))
+        args = args + (weights.reshape(n).astype(jnp.float32),)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -111,7 +111,7 @@ def qsgd_reduce_pallas(codes, norms, weights=None, *, levels: int = 127,
     if interpret is None:
         interpret = not on_tpu()
     if rows is None:
-        rows = autotune_rows(nb, b, n_buffers=3)
+        rows = autotune_rows(nb, 3 * b * 4, min_itemsize=1)
     return _qsgd_reduce_pallas(codes, norms, weights, levels=levels,
                                rows=rows, interpret=interpret,
                                has_w=weights is not None)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dispatch import default_interpret
+
 __all__ = ["selective_scan"]
 
 
@@ -59,10 +61,13 @@ def _scan_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, o_ref, h_ref, *,
                    static_argnames=("chunk", "e_blk", "interpret"))
 def selective_scan(dt: jax.Array, Bm: jax.Array, Cm: jax.Array, x: jax.Array,
                    A: jax.Array, *, chunk: int = 64, e_blk: int = 128,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool = None) -> jax.Array:
     """dt/x: (B, L, E); Bm/Cm: (B, L, N); A: (E, N).  Returns y (B, L, E).
     L must be a multiple of ``chunk`` (callers pad); E a multiple of e_blk
-    or smaller."""
+    or smaller.  ``interpret=None`` follows the backend (compiled on
+    TPU, interpreter elsewhere)."""
+    if interpret is None:
+        interpret = default_interpret()
     B, L, E = x.shape
     N = A.shape[1]
     e_blk = min(e_blk, E)

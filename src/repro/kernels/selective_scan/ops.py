@@ -9,7 +9,7 @@ __all__ = ["selective_scan_op"]
 
 
 def selective_scan_op(dt, Bm, Cm, x, A, *, chunk: int = 64, e_blk: int = 128,
-                      interpret: bool = True):
+                      interpret: bool = None):
     B, L, E = x.shape
     pad = (-L) % chunk
     if pad:

@@ -28,13 +28,17 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ARCH_IDS, INPUT_SHAPES, ArchConfig, get_config
 from repro.core import L2GDHyper, make_compressor
 from repro.launch.mesh import client_axes, make_production_mesh, n_clients_of
-from repro.launch.roofline import (LINK_BW, analytic_flops, collective_stats,
+from repro.launch.roofline import (analytic_flops, collective_stats,
                                    model_flops, roofline_terms)
 from repro.launch.sharding import (batch_pspec, cache_pspecs, param_pspecs,
                                    tree_shardings)
 from repro.launch.steps import (build_prefill_step, build_serve_step,
                                 build_train_step, cache_specs, input_specs,
                                 param_shapes, state_specs)
+
+#: the chip the placeholder-device meshes stand for (a v5e pod slice);
+#: its peaks come from repro.launch.roofline.PEAKS
+TARGET_KIND = "TPU v5 lite"
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
@@ -247,8 +251,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax>=0.4.30: one dict per device
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     cfg = get_config(arch)
     shape = INPUT_SHAPES[shape_name]
@@ -264,7 +266,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     tmp_b = getattr(mem, "temp_size_in_bytes", 0) or 0
     bytes_dev = float(arg_b + out_b + 2 * tmp_b)
     terms = roofline_terms(flops_dev, bytes_dev,
-                           coll["wire_bytes_per_device"])
+                           coll["wire_bytes_per_device"], TARGET_KIND)
     # MODEL_FLOPS = 6·N_active·D (train) / 2·N_active·D (inference)
     mf = model_flops(n_act, meta["tokens"])
     if meta["kind"] != "train":

@@ -19,27 +19,19 @@ traffic.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:
-    from jax.sharding import AxisType
-except ImportError:  # older jax: no explicit-sharding axis types
-    AxisType = None
-
-__all__ = ["make_compat_mesh", "make_production_mesh", "make_client_mesh",
+__all__ = ["make_mesh", "make_production_mesh", "make_client_mesh",
            "make_train_mesh", "client_axes", "n_clients_of",
            "model_shards_of"]
 
 
-def make_compat_mesh(shape, axes, devices):
-    """jax.make_mesh across jax versions: newer jax wants explicit
-    AxisType.Auto axis types, older jax has neither the kwarg nor the
-    enum.  The single compat implementation — tests use it too."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    import numpy as np
-    from jax.sharding import Mesh
-    return Mesh(np.asarray(devices).reshape(shape), axes)
+def make_mesh(shape, axes, devices):
+    """``jax.make_mesh`` over ``devices`` with every axis
+    ``AxisType.Auto`` (GSPMD-propagated shardings) — the one mesh
+    constructor of the repo; tests use it too."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, clients: int = None,
@@ -60,14 +52,14 @@ def make_production_mesh(*, multi_pod: bool = False, clients: int = None,
             raise ValueError("multi_pod composes the pod axis with the "
                              "default data x model shape; pass clients=/"
                              "model= without multi_pod")
-        return make_compat_mesh((c, m), ("clients", "model"),
+        return make_mesh((c, m), ("clients", "model"),
                                 jax.devices()[:c * m])
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = 1
     for s in shape:
         n *= s
-    return make_compat_mesh(shape, axes, jax.devices()[:n])
+    return make_mesh(shape, axes, jax.devices()[:n])
 
 
 def make_train_mesh(clients: int = None, model_shards: int = 1):
@@ -84,7 +76,7 @@ def make_train_mesh(clients: int = None, model_shards: int = 1):
     if c * m > len(devices):
         raise ValueError(f"mesh ({c} clients x {m} model shards) needs "
                          f"{c * m} devices, have {len(devices)}")
-    return make_compat_mesh((c, m), ("clients", "model"), devices[:c * m])
+    return make_mesh((c, m), ("clients", "model"), devices[:c * m])
 
 
 def make_client_mesh(n_shards: int = None):
@@ -95,7 +87,7 @@ def make_client_mesh(n_shards: int = None):
     jax import; see benchmarks/bench_sharded_rollout.py)."""
     devices = jax.devices()
     n = len(devices) if n_shards is None else int(n_shards)
-    return make_compat_mesh((n,), ("clients",), devices[:n])
+    return make_mesh((n,), ("clients",), devices[:n])
 
 
 def client_axes(mesh) -> tuple:

@@ -1,7 +1,7 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Hardware model (TPU v5e-class, per chip):
-  PEAK_FLOPS = 197e12 bf16, HBM_BW = 819e9 B/s, LINK_BW = 50e9 B/s / ICI link.
+Hardware model: per-chip peaks keyed by ``device_kind`` (:data:`PEAKS`,
+:func:`peaks_for`); a device that is not in the table is an error.
 
 IMPORTANT CAVEAT (validated empirically, see EXPERIMENTS.md §Dry-run):
 XLA's HloCostAnalysis counts a while-loop BODY exactly once, independent of
@@ -28,9 +28,23 @@ from __future__ import annotations
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: "TPU v5 lite" (TPU v5e) — Google Cloud documentation, "TPU v5e":
+#: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+#: interconnect, i.e. 50 GB/s on each of its four ICI links.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peaks_for(device_kind: str) -> Dict[str, float]:
+    """The :data:`PEAKS` row of ``device_kind``; unknown kinds raise
+    instead of borrowing another chip's numbers."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -158,10 +172,11 @@ def collective_stats(hlo_text: str, loop_trip: int = 1) -> Dict:
 
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
-                   wire_bytes_per_dev: float) -> Dict:
-    t_c = flops_per_dev / PEAK_FLOPS
-    t_m = bytes_per_dev / HBM_BW
-    t_x = wire_bytes_per_dev / LINK_BW
+                   wire_bytes_per_dev: float, device_kind: str) -> Dict:
+    peaks = peaks_for(device_kind)
+    t_c = flops_per_dev / peaks["flops"]
+    t_m = bytes_per_dev / peaks["hbm_bw"]
+    t_x = wire_bytes_per_dev / peaks["link_bw"]
     dominant = max(("compute", t_c), ("memory", t_m), ("collective", t_x),
                    key=lambda kv: kv[1])[0]
     return {"compute_s": t_c, "memory_s": t_m, "collective_s": t_x,

@@ -14,6 +14,11 @@ batch — prefill (TTFT) and greedy decode — with no per-token host sync.
   # serve a federated checkpoint produced by train.py:
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b \
       --ckpt runs/ck.msgpack --codec qsgd4
+
+The architecture flags are train.py's (``--full`` for the published
+widths, ``--layers`` to cut depth, ...), built by the same
+:func:`repro.launch.train.arch_config`.  ``main`` returns the served
+results for callers such as ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -22,8 +27,9 @@ import argparse
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ARCH_IDS, get_config
 from repro.core import make_compressor, make_plan
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.train import add_arch_args, arch_config
 from repro.models import init_params
 from repro.serve import DeltaModelStore, Request, ServingEngine
 
@@ -47,9 +53,12 @@ def build_plan(name: str):
     raise ValueError(f"unknown codec {name!r}; have {CODECS}")
 
 
-def main() -> None:
+def main(argv=None) -> dict:
+    """CLI entry point; ``argv`` replaces ``sys.argv[1:]``.  Returns
+    ``{"arch", "results", "models_per_gb"}`` — ``results`` is
+    :meth:`ServingEngine.serve`'s per-request list."""
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCH_IDS, default="gemma3-1b")
+    add_arch_args(ap, "gemma3-1b")
     ap.add_argument("--tenants", type=int, default=4,
                     help="synthetic tenants when no --ckpt is given")
     ap.add_argument("--cache", type=int, default=2,
@@ -63,9 +72,10 @@ def main() -> None:
                     help="federated checkpoint (stacked client params) "
                          "to ingest as tenants")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = get_config(args.arch).reduced()
+    cfg = arch_config(args)
     plan, narrow = build_plan(args.codec)
     key = jax.random.PRNGKey(args.seed)
 
@@ -111,6 +121,8 @@ def main() -> None:
           f"evictions={snap['evictions']}; "
           f"throughput ~{agg_tok / agg_t:.1f} tokens/s "
           f"over {snap['batches']} batches")
+    return {"arch": cfg.name, "results": results,
+            "models_per_gb": store.models_per_gb()}
 
 
 if __name__ == "__main__":

@@ -213,12 +213,17 @@ def _temp_bytes(fn, *specs):
 
 
 def test_aggregation_allocates_no_nd_fp32():
-    """The O(d)-accumulator claim at the HLO level: compiled temp bytes
-    of the fused aggregation stay well under ONE (n, d) fp32 buffer,
-    while the decode-then-mean reference allocates at least that much.
-    The model is a single (d,) leaf with d a bucket multiple, so the
-    encode side adds no ravel/pad copies and the bound isolates the
-    server reduce."""
+    """The O(d)-accumulator claim at the HLO level, on the CPU route:
+    compiled temp bytes of the fused aggregation stay well under ONE
+    (n, d) fp32 buffer.  The model is a single (d,) leaf with d a bucket
+    multiple, so the encode side adds no ravel/pad copies and the bound
+    isolates the server reduce.
+
+    There is no lower bound on the decode-then-mean reference: XLA:CPU
+    fuses the decode into the mean and allocates no (n, d) temp for it,
+    so it no longer shows what an unfused server costs.  The device-side
+    proof is the TPU compile of the Pallas reduce in
+    tests/test_tpu_compile.py."""
     n, d = 16, 64 * 2048                       # (n, d) fp32 = 8 MiB
     plan = make_plan(make_compressor("qsgd"), {"w": jnp.zeros((d,))})
     payload_spec = jax.eval_shape(
@@ -229,19 +234,13 @@ def test_aggregation_allocates_no_nd_fp32():
     nd_bytes = n * d * 4
     fused = _temp_bytes(lambda p: reduce_payload_mean(p, None),
                         payload_spec)
-    ref = _temp_bytes(
-        lambda p: masked_client_mean(jax.vmap(plan.decode)(p), None),
-        payload_spec)
-    assert ref >= nd_bytes, (ref, nd_bytes)            # metric sanity
     assert fused < nd_bytes // 2, (fused, nd_bytes)
 
     # end-to-end: the whole compressed_average (encode + reduce + C_M).
-    # The CLIENT-side encode keeps one (n, d) f32 temp — XLA:CPU
-    # materializes the x^2 operand of the bucket-norm reduce-window
-    # (input-sized work, present in every path since the seed) — but the
-    # SERVER side adds only the O(d) accumulator: total temps stay
-    # within a few KiB of that single encode buffer instead of the
-    # decode path's extra (n, d) dequantized tree.
+    # The CLIENT-side encode runs one client at a time
+    # (flatbuf.encode_clients), and the SERVER side adds only the O(d)
+    # accumulator: total temps stay within a few KiB of one (n, d)
+    # buffer instead of the decode path's extra (n, d) dequantized tree.
     e2e = _temp_bytes(
         lambda k, p: compressed_average(k, p, plan, Identity()),
         jax.ShapeDtypeStruct((2,), jnp.uint32),

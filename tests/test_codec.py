@@ -65,6 +65,26 @@ def test_pack_unpack_bits_roundtrip(width):
                                   np.asarray(fields))
 
 
+@pytest.mark.parametrize("width", [1, 2, 4])
+@pytest.mark.parametrize("size", [16, 2056, 4096, 3 * 4096 + 8])
+def test_lane_dense_pack_bits_matches(width, size):
+    """The form of pack/unpack for 1-D buffers (a matmul over 128-byte
+    rows) gives the same bytes and fields as the shift form that 2-D
+    buffers take, also where the buffer is not a whole number of rows."""
+    rng = np.random.default_rng(width)
+    fields = jnp.asarray(rng.integers(0, 1 << width, size=size), jnp.uint8)
+    shift = pack_bits(fields[None], width)[0]
+    lanes = pack_bits(fields, width)
+    assert lanes.dtype == shift.dtype and lanes.shape == shift.shape
+    np.testing.assert_array_equal(np.asarray(lanes), np.asarray(shift))
+    unpacked = unpack_bits(lanes, width)
+    assert unpacked.dtype == jnp.uint32
+    np.testing.assert_array_equal(np.asarray(unpacked),
+                                  np.asarray(unpack_bits(shift[None],
+                                                         width)[0]))
+    np.testing.assert_array_equal(np.asarray(unpacked), np.asarray(fields))
+
+
 # --------------------------------------------------------------------------
 # Payload.nbits == tree_wire_bits == plan.round_bits (acceptance)
 # --------------------------------------------------------------------------

@@ -50,8 +50,8 @@ def test_mlp_fused_equivalent_family():
 
 
 def _mesh_1x1():
-    from repro.launch.mesh import make_compat_mesh
-    return make_compat_mesh((1, 1), ("data", "model"), jax.devices()[:1])
+    from repro.launch.mesh import make_mesh
+    return make_mesh((1, 1), ("data", "model"), jax.devices()[:1])
 
 
 def test_sharded_average_unbiased_single_device():

@@ -114,8 +114,8 @@ from repro.configs.base import get_config, INPUT_SHAPES
 from repro.core import L2GDHyper, make_compressor
 from repro.launch.sharding import param_pspecs, tree_shardings, batch_pspec
 from repro.launch.steps import build_train_step, state_specs, input_specs
-from repro.launch.mesh import make_compat_mesh
-mesh = make_compat_mesh((2, 4), ("data", "model"), jax.devices())
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"), jax.devices())
 cfg = get_config("granite-moe-1b-a400m").reduced()
 shape = dataclasses.replace(INPUT_SHAPES["train_4k"], seq_len=32, global_batch=4)
 hp = L2GDHyper(eta=0.1, lam=1.0, p=0.3, n=2)
@@ -134,8 +134,6 @@ with mesh:
                        jax.ShapeDtypeStruct((2,), jnp.uint32))
     compiled = lowered.compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns a singleton list
-        ca = ca[0]
     assert ca["flops"] > 0
     # the compiled module must actually contain cross-client collectives
     txt = compiled.as_text()
@@ -147,3 +145,16 @@ print("MINI-DRYRUN-OK")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, timeout=420)
     assert "MINI-DRYRUN-OK" in out.stdout, out.stderr[-3000:]
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Roofline terms use the named chip's published peaks; a device
+    kind missing from the table raises instead of borrowing v5e's."""
+    from repro.launch.roofline import PEAKS, roofline_terms
+    v5e = PEAKS["TPU v5 lite"]
+    t = roofline_terms(v5e["flops"], 2 * v5e["hbm_bw"], 0.0, "TPU v5 lite")
+    assert t["compute_s"] == pytest.approx(1.0)
+    assert t["memory_s"] == pytest.approx(2.0)
+    assert t["dominant"] == "memory"
+    with pytest.raises(KeyError, match="cpu"):
+        roofline_terms(1.0, 1.0, 1.0, "cpu")
