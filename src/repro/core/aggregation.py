@@ -152,6 +152,8 @@ def compressed_average(key: jax.Array, params_stacked,
     n = jax.tree_util.tree_leaves(params_stacked)[0].shape[0]
     k_clients, k_master = jax.random.split(key)
     client_keys = jax.random.split(k_clients, n)
+    # named stages for a device trace: uplink_encode, server_reduce,
+    # downlink (a mixed fleet names its own two stages in fleet_mean)
     if not isinstance(up_plan, CompressionPlan):
         from repro.fl.fleet import fleet_mean
         if up_plan.n_clients != n:
@@ -163,27 +165,34 @@ def compressed_average(key: jax.Array, params_stacked,
         # ONE-pass kernel accumulates the masked mean straight from the
         # packed codes — no per-client dequantized tree is materialized
         from repro.core import flatbuf
-        payload = flatbuf.encode_clients(up_plan, client_keys,
-                                         params_stacked)
-        ybar = flatbuf.reduce_payload_mean(payload, mask)
+        with jax.named_scope("uplink_encode"):
+            payload = flatbuf.encode_clients(up_plan, client_keys,
+                                             params_stacked)
+        with jax.named_scope("server_reduce"):
+            ybar = flatbuf.reduce_payload_mean(payload, mask)
     else:
-        compressed = jax.vmap(lambda k, p: up_plan.apply(k, p))(
-            client_keys, params_stacked)
+        with jax.named_scope("uplink_encode"):
+            compressed = jax.vmap(lambda k, p: up_plan.apply(k, p))(
+                client_keys, params_stacked)
         # fail-fast payload validation (mask-and-count, mirroring
         # reduce_payload_mean): exclude non-finite clients from numerator
         # AND denominator; select the historic expression when everything
         # is finite so that path stays bit-identical
-        fin = stacked_finite_mask(compressed)
-        all_ok = jnp.min(fin) > 0 if fin.shape[0] else jnp.bool_(True)
-        w = fin if mask is None else mask.reshape(-1).astype(jnp.float32) * fin
-        denom = jnp.sum(w)
-        guarded = jax.tree.map(
-            lambda s: s / jnp.where(denom > 0, denom, 1.0).astype(s.dtype),
-            weighted_client_sum(compressed, w))
-        plain = masked_client_mean(compressed, mask)
-        ybar = jax.tree.map(lambda p, g: jnp.where(all_ok, p, g),
-                            plain, guarded)
-    return down_plan.apply(k_master, ybar)
+        with jax.named_scope("server_reduce"):
+            fin = stacked_finite_mask(compressed)
+            all_ok = jnp.min(fin) > 0 if fin.shape[0] else jnp.bool_(True)
+            w = fin if mask is None else \
+                mask.reshape(-1).astype(jnp.float32) * fin
+            denom = jnp.sum(w)
+            guarded = jax.tree.map(
+                lambda s: s / jnp.where(denom > 0, denom,
+                                        1.0).astype(s.dtype),
+                weighted_client_sum(compressed, w))
+            plain = masked_client_mean(compressed, mask)
+            ybar = jax.tree.map(lambda p, g: jnp.where(all_ok, p, g),
+                                plain, guarded)
+    with jax.named_scope("downlink"):
+        return down_plan.apply(k_master, ybar)
 
 
 def stochastic_round_cast(key: jax.Array, x: jax.Array,
@@ -332,13 +341,15 @@ def _gather_payloads(payload, axes, *, batched: bool):
             return a.reshape(a.shape[:-1] + (-1, _LANES))
         return a
 
-    gathered = jax.tree_util.tree_map(as_rows, payload)
-    for ax in axes:                           # wire arrays on the wire
-        gathered = jax.tree_util.tree_map(
-            lambda a: jax.lax.all_gather(a, ax), gathered)
-    tail = (lambda o: o.shape[1:]) if batched else (lambda o: o.shape)
-    return jax.tree_util.tree_map(
-        lambda orig, g: g.reshape((-1,) + tail(orig)), payload, gathered)
+    with jax.named_scope("gather"):
+        gathered = jax.tree_util.tree_map(as_rows, payload)
+        for ax in axes:                       # wire arrays on the wire
+            gathered = jax.tree_util.tree_map(
+                lambda a: jax.lax.all_gather(a, ax), gathered)
+        tail = (lambda o: o.shape[1:]) if batched else (lambda o: o.shape)
+        return jax.tree_util.tree_map(
+            lambda orig, g: g.reshape((-1,) + tail(orig)), payload,
+            gathered)
 
 
 def _gather_reduce(plan, payload, axes, *, batched: bool, mask=None):
@@ -351,15 +362,16 @@ def _gather_reduce(plan, payload, axes, *, batched: bool, mask=None):
     decode + masked mean for leafwise payload trees."""
     from repro.core import flatbuf
     gathered = _gather_payloads(payload, axes, batched=batched)
-    if flatbuf.supports_fused_reduce(gathered):
-        return flatbuf.reduce_payload_mean(gathered, mask)
-    deq = jax.vmap(plan.decode)(gathered)
-    if mask is None and not batched:
-        # make_payload_sharded_average's historic per-shard mean (decoded
-        # leaves may be non-f32; keep the f32 accumulate)
-        return jax.tree_util.tree_map(
-            lambda a: jnp.mean(a.astype(jnp.float32), axis=0), deq)
-    return masked_client_mean(deq, mask)
+    with jax.named_scope("server_reduce"):
+        if flatbuf.supports_fused_reduce(gathered):
+            return flatbuf.reduce_payload_mean(gathered, mask)
+        deq = jax.vmap(plan.decode)(gathered)
+        if mask is None and not batched:
+            # make_payload_sharded_average's historic per-shard mean
+            # (decoded leaves may be non-f32; keep the f32 accumulate)
+            return jax.tree_util.tree_map(
+                lambda a: jnp.mean(a.astype(jnp.float32), axis=0), deq)
+        return masked_client_mean(deq, mask)
 
 
 def make_client_sharded_average(axis_name: str, n_clients: int,
@@ -425,11 +437,15 @@ def make_client_sharded_average(axis_name: str, n_clients: int,
             local_keys = jax.random.wrap_key_data(
                 jax.lax.dynamic_slice_in_dim(
                     ckd, jax.lax.axis_index(axis_name) * m, m))
-            payload = flatbuf.encode_clients(up_plan, local_keys,
-                                             params_local)
+            # the stacked engine's stages (compressed_average), with the
+            # all_gather between them named "gather"
+            with jax.named_scope("uplink_encode"):
+                payload = flatbuf.encode_clients(up_plan, local_keys,
+                                                 params_local)
             ybar = _gather_reduce(up_plan, payload, (axis_name,),
                                   batched=True, mask=mask)
-            return down_plan.apply(k_master, ybar)
+            with jax.named_scope("downlink"):
+                return down_plan.apply(k_master, ybar)
 
         return average_fn
 
@@ -453,35 +469,42 @@ def make_client_sharded_average(axis_name: str, n_clients: int,
                 [1.0 if a == c else 0.0 for a in fleet.assignment],
                 jnp.float32)
             if plan_c.transport in ("flat", "packed"):
-                payload = flatbuf.encode_clients(plan_c, local_keys,
-                                                 params_local)
+                with jax.named_scope("uplink_encode"):
+                    payload = flatbuf.encode_clients(plan_c, local_keys,
+                                                     params_local)
                 gathered = _gather_payloads(payload, (axis_name,),
                                             batched=True)
-                fin = flatbuf.payload_finite_mask(gathered)
-                gathered = flatbuf.sanitize_payload(gathered, fin)
-                w = member * base * fin
-                layout = gathered.layout
-                acc = flatbuf.reduce_payload_acc(gathered, w)
-                part = flatbuf.unravel(layout,
-                                       flatbuf.unbucketize(acc, layout.d))
+                with jax.named_scope("server_reduce"):
+                    fin = flatbuf.payload_finite_mask(gathered)
+                    gathered = flatbuf.sanitize_payload(gathered, fin)
+                    w = member * base * fin
+                    layout = gathered.layout
+                    acc = flatbuf.reduce_payload_acc(gathered, w)
+                    part = flatbuf.unravel(
+                        layout, flatbuf.unbucketize(acc, layout.d))
             else:
-                contrib = jax.vmap(lambda k, p: plan_c.apply(k, p))(
-                    local_keys, params_local)
+                with jax.named_scope("uplink_encode"):
+                    contrib = jax.vmap(lambda k, p: plan_c.apply(k, p))(
+                        local_keys, params_local)
                 gathered = _gather_payloads(contrib, (axis_name,),
                                             batched=True)
-                fin = stacked_finite_mask(gathered)
-                w = member * base * fin
-                part = weighted_client_sum(gathered, w)
-            part = jax.tree_util.tree_map(
-                lambda a: a.astype(jnp.float32), part)
-            total = part if total is None else jax.tree_util.tree_map(
-                jnp.add, total, part)
-            wsum = wsum + w
-        denom = jnp.sum(wsum)
-        safe = jnp.where(denom > 0, denom, 1.0)
-        ybar = jax.tree_util.tree_map(
-            lambda s, a: (s / safe).astype(a.dtype), total, params_local)
-        return down_plan.apply(k_master, ybar)
+                with jax.named_scope("server_reduce"):
+                    fin = stacked_finite_mask(gathered)
+                    w = member * base * fin
+                    part = weighted_client_sum(gathered, w)
+            with jax.named_scope("server_reduce"):
+                part = jax.tree_util.tree_map(
+                    lambda a: a.astype(jnp.float32), part)
+                total = part if total is None else jax.tree_util.tree_map(
+                    jnp.add, total, part)
+                wsum = wsum + w
+        with jax.named_scope("server_reduce"):
+            denom = jnp.sum(wsum)
+            safe = jnp.where(denom > 0, denom, 1.0)
+            ybar = jax.tree_util.tree_map(
+                lambda s, a: (s / safe).astype(a.dtype), total, params_local)
+        with jax.named_scope("downlink"):
+            return down_plan.apply(k_master, ybar)
 
     return average_fn
 

@@ -253,43 +253,58 @@ def l2gd_step(state: L2GDState, batch, xi_k: jax.Array, key: jax.Array,
             participation_mask, jax.lax.axis_index(axis_name) * m, m)
 
     def _mean_loss(st):
-        losses, _ = jax.vmap(grad_fn)(st.params, batch)
-        return _reduce_losses(losses)
+        with jax.named_scope("loss"):
+            losses, _ = jax.vmap(grad_fn)(st.params, batch)
+            return _reduce_losses(losses)
 
+    def _local_pass(params):
+        with jax.named_scope("grad"):
+            losses, grads = jax.vmap(grad_fn)(params, batch)
+        with jax.named_scope("update"):
+            return losses, local_update(params, grads, hp)
+
+    # each branch runs under a named scope (l2gd.local, l2gd.agg_fresh,
+    # l2gd.agg_cached) with named stages inside: a device trace then
+    # gives every op its branch and stage
+    @jax.named_scope("l2gd.local")
     def branch_local(op):
         st, k = op
-        losses, grads = jax.vmap(grad_fn)(st.params, batch)
-        new_params = local_update(st.params, grads, hp)
+        losses, new_params = _local_pass(st.params)
         # LoCoDL burst: H-1 further passes on the SAME batch (unrolled —
         # H is static and small).  The reported loss stays the pre-update
         # loss of the first pass, so the trace semantics match H=1.
         for _ in range(local_steps - 1):
-            _, grads = jax.vmap(grad_fn)(new_params, batch)
-            new_params = local_update(new_params, grads, hp)
+            _, new_params = _local_pass(new_params)
         return (L2GDState(new_params, st.cache, jnp.asarray(0, jnp.int32),
                           st.step + 1),
                 _reduce_losses(losses))
 
+    @jax.named_scope("l2gd.agg_fresh")
     def branch_agg_fresh(op):
         st, k = op
-        if average_fn is not None:
-            if participation_mask is None:
-                target = average_fn(k, st.params)
+        with jax.named_scope("average"):
+            if average_fn is not None:
+                if participation_mask is None:
+                    target = average_fn(k, st.params)
+                else:
+                    target = average_fn(k, st.params, participation_mask)
             else:
-                target = average_fn(k, st.params, participation_mask)
-        else:
-            target = compressed_average(k, st.params, up_plan, down_plan,
-                                        mask=participation_mask)
-        new_params = aggregation_update(st.params, target, hp,
-                                        mask=local_mask)
+                target = compressed_average(k, st.params, up_plan,
+                                            down_plan,
+                                            mask=participation_mask)
+        with jax.named_scope("apply"):
+            new_params = aggregation_update(st.params, target, hp,
+                                            mask=local_mask)
         return (L2GDState(new_params, target, jnp.asarray(1, jnp.int32),
                           st.step + 1),
                 _mean_loss(st))
 
+    @jax.named_scope("l2gd.agg_cached")
     def branch_agg_cached(op):
         st, k = op
-        new_params = aggregation_update(st.params, st.cache, hp,
-                                        mask=local_mask)
+        with jax.named_scope("apply"):
+            new_params = aggregation_update(st.params, st.cache, hp,
+                                            mask=local_mask)
         return (L2GDState(new_params, st.cache, jnp.asarray(1, jnp.int32),
                           st.step + 1),
                 _mean_loss(st))

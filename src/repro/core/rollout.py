@@ -208,24 +208,24 @@ def rollout_l2gd(key: jax.Array, state: L2GDState, hp: L2GDHyper, batches,
     # device; a Python-float closure would constant-fold in f64 and
     # break stacked-vs-sharded bit-exactness — same rule as the driver)
     hp = jax.tree_util.tree_map(jnp.asarray, hp)
-    xi_key, noise_key = jax.random.split(key)
-
     # pre-derive both streams for the whole window in two vectorized
     # threefry passes (bit-identical to per-step fold_in: vmap of fold_in
     # IS fold_in per element) — the scan body then carries no RNG graphs,
     # which cuts trace/compile time and per-iteration overhead
-    ks = state.step + jnp.arange(length, dtype=jnp.int32)
-    if xi_trace is None:
-        xis_in = jax.vmap(lambda k: draw_xi(jax.random.fold_in(xi_key, k),
-                                            hp.p))(ks)
-    else:
-        xis_in = xi_trace.astype(jnp.int32)
-    subs = jax.vmap(lambda k: jax.random.fold_in(noise_key, k))(ks)
-    masks = None
-    if participation is not None:
-        s = participant_count(hp.n, participation)
-        if s < hp.n:  # s == n: no masks — bit-identical to the base path
-            masks = participation_masks(xi_key, ks, hp.n, s)
+    with jax.named_scope("rollout.streams"):
+        xi_key, noise_key = jax.random.split(key)
+        ks = state.step + jnp.arange(length, dtype=jnp.int32)
+        if xi_trace is None:
+            xis_in = jax.vmap(lambda k: draw_xi(
+                jax.random.fold_in(xi_key, k), hp.p))(ks)
+        else:
+            xis_in = xi_trace.astype(jnp.int32)
+        subs = jax.vmap(lambda k: jax.random.fold_in(noise_key, k))(ks)
+        masks = None
+        if participation is not None:
+            s = participant_count(hp.n, participation)
+            if s < hp.n:  # s == n: no masks, the base path bit-exactly
+                masks = participation_masks(xi_key, ks, hp.n, s)
 
     def step_fn(st, batch, xi, sub, mask):
         return l2gd_step(st, batch, xi, sub, grad_fn, hp, client_comp,
@@ -339,21 +339,22 @@ def rollout_l2gd_sharded(key: jax.Array, state: L2GDState, hp: L2GDHyper,
     average_fn = make_client_sharded_average(axis_name, n, up_plan,
                                              down_plan)
 
-    xi_key, noise_key = jax.random.split(key)
-    ks = state.step + jnp.arange(length, dtype=jnp.int32)
-    if xi_trace is None:
-        xis_in = jax.vmap(lambda k: draw_xi(jax.random.fold_in(xi_key, k),
-                                            hp.p))(ks)
-    else:
-        xis_in = jnp.asarray(xi_trace).astype(jnp.int32)
-    # keys cross the shard_map boundary as raw key data (uint32 rows)
-    subs = jax.random.key_data(
-        jax.vmap(lambda k: jax.random.fold_in(noise_key, k))(ks))
-    masks = None
-    if participation is not None:
-        s = participant_count(n, participation)
-        if s < n:
-            masks = participation_masks(xi_key, ks, n, s)
+    with jax.named_scope("rollout.streams"):
+        xi_key, noise_key = jax.random.split(key)
+        ks = state.step + jnp.arange(length, dtype=jnp.int32)
+        if xi_trace is None:
+            xis_in = jax.vmap(lambda k: draw_xi(
+                jax.random.fold_in(xi_key, k), hp.p))(ks)
+        else:
+            xis_in = jnp.asarray(xi_trace).astype(jnp.int32)
+        # keys cross the shard_map boundary as raw key data (uint32 rows)
+        subs = jax.random.key_data(
+            jax.vmap(lambda k: jax.random.fold_in(noise_key, k))(ks))
+        masks = None
+        if participation is not None:
+            s = participant_count(n, participation)
+            if s < n:
+                masks = participation_masks(xi_key, ks, n, s)
 
     def sharded_body(xis_in, subs, masks, st, batches, hp):
         def step_fn(st, batch, xi, sub_data, mask):

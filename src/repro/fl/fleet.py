@@ -368,14 +368,16 @@ def fleet_mean(fleet: FleetPlan, client_keys, params_stacked, mask=None):
     ONE division by the total weight (not per cohort), so cohort
     grouping changes the mean only by f32 association order."""
     n = fleet.n_clients
-    batches = fleet_encode(fleet, client_keys, params_stacked)
-    fin = fleet_finite_mask(batches, n)
-    if mask is None:
-        w = fin
-    else:
-        w = mask.reshape(-1).astype(jnp.float32) * fin
-    denom = jnp.sum(w)
-    safe = jnp.where(denom > 0, denom, 1.0)
-    total = fleet_weighted_sum(batches, w)
-    return jax.tree_util.tree_map(
-        lambda s, a: (s / safe).astype(a.dtype), total, params_stacked)
+    with jax.named_scope("uplink_encode"):
+        batches = fleet_encode(fleet, client_keys, params_stacked)
+    with jax.named_scope("server_reduce"):
+        fin = fleet_finite_mask(batches, n)
+        if mask is None:
+            w = fin
+        else:
+            w = mask.reshape(-1).astype(jnp.float32) * fin
+        denom = jnp.sum(w)
+        safe = jnp.where(denom > 0, denom, 1.0)
+        total = fleet_weighted_sum(batches, w)
+        return jax.tree_util.tree_map(
+            lambda s, a: (s / safe).astype(a.dtype), total, params_stacked)

@@ -104,5 +104,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom
             pltpu.VMEM((bq, D), jnp.float32),   # accumulator
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

@@ -65,6 +65,7 @@ def natural_compress_2d(x2d: jax.Array, noise: jax.Array, *, rows: int = None,
                   pl.BlockSpec((rows, b), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, b), x2d.dtype),
+        name="natural_compress_2d",
         interpret=interpret,
     )(x2d, noise)
 
@@ -96,6 +97,7 @@ def natural_fused_pallas(x2d: jax.Array, seeds: jax.Array, *,
                   pl.BlockSpec((rows, b), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, b), x2d.dtype),
+        name="natural_fused_pallas",
         interpret=interpret,
     )(seeds.reshape(1, 2), x2d)
 

@@ -105,6 +105,7 @@ def _natural_reduce_pallas(exps, signs, weights, *, rows: int,
         out_specs=pl.BlockSpec((rows, b), lambda t, i: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, b), jnp.float32),
         scratch_shapes=[pltpu.VMEM((rows, b), jnp.float32)],
+        name="_natural_reduce_pallas",
         interpret=interpret,
     )(*args)
 

@@ -89,6 +89,7 @@ def qsgd_dequantized(x2d: jax.Array, noise: jax.Array, *, levels: int = 127,
         ],
         out_specs=pl.BlockSpec((rows, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, b), x2d.dtype),
+        name="qsgd_dequantized",
         interpret=interpret,
     )(x2d, noise)
 
@@ -129,6 +130,7 @@ def qsgd_fused_pallas(x2d: jax.Array, seeds: jax.Array, *, levels: int = 127,
         ],
         out_specs=pl.BlockSpec((rows, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, b), x2d.dtype),
+        name="qsgd_fused_pallas",
         interpret=interpret,
     )(seeds.reshape(1, 2), x2d)
 
@@ -192,6 +194,7 @@ def qsgd_pack_pallas(x2d: jax.Array, seeds: jax.Array, *, levels: int = 127,
             jax.ShapeDtypeStruct((n, b), jnp.int8),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
+        name="qsgd_pack_pallas",
         interpret=interpret,
     )(seeds.reshape(1, 2), x2d)
 
@@ -231,6 +234,7 @@ def qsgd_unpack_pallas(codes: jax.Array, norms: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((rows, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
+        name="qsgd_unpack_pallas",
         interpret=interpret,
     )(codes, norms)
 

@@ -97,6 +97,7 @@ def _qsgd_reduce_pallas(codes, norms, weights, *, levels: int, rows: int,
         out_specs=pl.BlockSpec((rows, b), lambda t, i: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, b), jnp.float32),
         scratch_shapes=[pltpu.VMEM((rows, b), jnp.float32)],
+        name="_qsgd_reduce_pallas",
         interpret=interpret,
     )(*args)
 

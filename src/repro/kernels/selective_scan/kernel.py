@@ -86,5 +86,6 @@ def selective_scan(dt: jax.Array, Bm: jax.Array, Cm: jax.Array, x: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, e_blk), lambda b, e, c: (b, c, e)),
         out_shape=jax.ShapeDtypeStruct((B, L, E), x.dtype),
         scratch_shapes=[pltpu.VMEM((e_blk, N), jnp.float32)],
+        name="selective_scan",
         interpret=interpret,
     )(dt, Bm, Cm, x, A)

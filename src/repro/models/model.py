@@ -236,9 +236,13 @@ def _apply_ffn(cfg: ArchConfig, lp: dict, x, kind: str):
 
 def _decoder_layer_train(cfg: ArchConfig, ffn_kind: str, lp: dict, x,
                          positions, mask):
-    h = blocks.rmsnorm(lp["ln1"], x, cfg.norm_eps)
-    x = x + _apply_mixer_train(cfg, lp, h, positions, mask)
-    return _apply_ffn(cfg, lp, x, ffn_kind)
+    # named for a device trace, as are the embedding and the unembedding
+    # with its loss (forward, loss_fn)
+    with jax.named_scope("model.attn"):
+        h = blocks.rmsnorm(lp["ln1"], x, cfg.norm_eps)
+        x = x + _apply_mixer_train(cfg, lp, h, positions, mask)
+    with jax.named_scope("model.ffn"):
+        return _apply_ffn(cfg, lp, x, ffn_kind)
 
 
 def _scan_layers(cfg: ArchConfig, stacked, flags, ffn_kind: str, x,
@@ -342,9 +346,10 @@ def forward(params, cfg: ArchConfig, batch):
 
     tokens = batch["tokens"]
     cdt = _dtype(cfg.compute_dtype)
-    x = blocks.embed(params["embed"], tokens).astype(cdt)
-    if cfg.frontend == "vision" and "patches" in batch:
-        x = jnp.concatenate([batch["patches"].astype(cdt), x], axis=1)
+    with jax.named_scope("model.embed"):
+        x = blocks.embed(params["embed"], tokens).astype(cdt)
+        if cfg.frontend == "vision" and "patches" in batch:
+            x = jnp.concatenate([batch["patches"].astype(cdt), x], axis=1)
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     mask_g, mask_w = _build_masks(cfg, S)
@@ -361,8 +366,9 @@ def forward(params, cfg: ArchConfig, batch):
     x, a = _scan_layers(cfg, params["layers"], flags, cfg.ffn, x, positions,
                         mask_g, mask_w)
     aux = aux + a
-    x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return blocks.unembed(params["embed"], x), aux
+    with jax.named_scope("model.unembed"):
+        x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        return blocks.unembed(params["embed"], x), aux
 
 
 def loss_fn(params, cfg: ArchConfig, batch):
@@ -370,9 +376,10 @@ def loss_fn(params, cfg: ArchConfig, batch):
     excluded from the loss."""
     logits, aux = forward(params, cfg, batch)
     tokens = batch["tokens"]
-    if cfg.frontend == "vision" and "patches" in batch:
-        logits = logits[:, batch["patches"].shape[1]:]
-    loss = blocks.cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+    with jax.named_scope("model.unembed"):
+        if cfg.frontend == "vision" and "patches" in batch:
+            logits = logits[:, batch["patches"].shape[1]:]
+        loss = blocks.cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
     total = loss + cfg.aux_loss_weight * aux
     return total, {"ce": loss, "aux": aux}
 

@@ -83,69 +83,84 @@ def _experts_apply(params, expert_in):
 
 
 def _moe_gather(params, x, *, n_experts: int, k: int, capacity: int):
-    """Scatter/gather dispatch.  x: (G,S,d)."""
+    """Scatter/gather dispatch.  x: (G,S,d).  Its stages are named for a
+    device trace: moe.route, moe.dispatch, moe.experts, moe.combine."""
     G, S, d = x.shape
     E, C = n_experts, capacity
-    gates, topv, topi = _route(x, params["router"], k)
+    with jax.named_scope("moe.route"):
+        gates, topv, topi = _route(x, params["router"], k)
 
-    # position of each (slot, token) inside its expert's capacity buffer.
-    # SLOT-MAJOR priority (all slot-0 assignments first), matching GShard —
-    # the einsum reference loops slots the same way, so capacity drops are
-    # identical between the two implementations.
-    flat_e = topi.swapaxes(1, 2).reshape(G, S * k)                 # (G,k*S)
-    oh = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)                # (G,k*S,E)
-    pos_all = jnp.cumsum(oh, axis=1) - oh                          # count before
-    pos = jnp.take_along_axis(pos_all, flat_e[..., None], axis=-1)[..., 0]
-    keep = pos < C                                                 # (G,k*S)
+        # position of each (slot, token) inside its expert's capacity
+        # buffer.  SLOT-MAJOR priority (all slot-0 assignments first),
+        # matching GShard — the einsum reference loops slots the same
+        # way, so capacity drops are identical between the two
+        # implementations.
+        flat_e = topi.swapaxes(1, 2).reshape(G, S * k)             # (G,k*S)
+        oh = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)            # (G,k*S,E)
+        pos_all = jnp.cumsum(oh, axis=1) - oh                # count before
+        pos = jnp.take_along_axis(pos_all, flat_e[..., None], axis=-1)[..., 0]
+        keep = pos < C                                             # (G,k*S)
 
-    # index map (expert*C + pos) -> flat token index; dropped -> sentinel
-    token_idx = jnp.arange(S * k, dtype=jnp.int32)[None, :] % S    # slot-major
-    token_idx = jnp.broadcast_to(token_idx, (G, S * k))
-    dest = flat_e * C + pos                                        # (G,S*k)
-    dest = jnp.where(keep, dest, E * C)                            # overflow bin
-    buf = jnp.full((G, E * C + 1), S, dtype=jnp.int32)             # S = pad token
-    buf = jax.vmap(lambda b, d_, t: b.at[d_].set(t))(buf, dest, token_idx)
-    idx_map = buf[:, : E * C].reshape(G, E, C)                     # (G,E,C)
+        # index map (expert*C + pos) -> flat token index (slot-major);
+        # dropped -> sentinel
+        token_idx = jnp.arange(S * k, dtype=jnp.int32)[None, :] % S
+        token_idx = jnp.broadcast_to(token_idx, (G, S * k))
+        dest = flat_e * C + pos                                    # (G,S*k)
+        dest = jnp.where(keep, dest, E * C)                  # overflow bin
+        buf = jnp.full((G, E * C + 1), S, dtype=jnp.int32)   # S = pad token
+        buf = jax.vmap(lambda b, d_, t: b.at[d_].set(t))(buf, dest, token_idx)
+        idx_map = buf[:, : E * C].reshape(G, E, C)                 # (G,E,C)
 
-    x_pad = jnp.concatenate([x, jnp.zeros((G, 1, d), x.dtype)], axis=1)
-    expert_in = jnp.take_along_axis(
-        x_pad[:, :, None, :].swapaxes(1, 2),                       # (G,1,S+1,d)
-        jnp.broadcast_to(idx_map[..., None], (G, E, C, 1)), axis=2)
-    expert_out = _experts_apply(params, expert_in)                 # (G,E,C,d)
+    with jax.named_scope("moe.dispatch"):
+        x_pad = jnp.concatenate([x, jnp.zeros((G, 1, d), x.dtype)], axis=1)
+        expert_in = jnp.take_along_axis(
+            x_pad[:, :, None, :].swapaxes(1, 2),             # (G,1,S+1,d)
+            jnp.broadcast_to(idx_map[..., None], (G, E, C, 1)), axis=2)
+    with jax.named_scope("moe.experts"):
+        expert_out = _experts_apply(params, expert_in)             # (G,E,C,d)
 
     # combine: gather each kept (slot, token)'s output and weight by gate
-    out_flat = expert_out.reshape(G, E * C, d)
-    src = jnp.where(keep, flat_e * C + pos, 0)
-    gathered = jnp.take_along_axis(
-        out_flat, src[..., None].astype(jnp.int32), axis=1)        # (G,k*S,d)
-    w = (topv.swapaxes(1, 2).reshape(G, S * k) * keep).astype(gathered.dtype)
-    y = jnp.sum((gathered * w[..., None]).reshape(G, k, S, d), axis=1)
-    return y, _aux_loss(gates, topi, E)
+    with jax.named_scope("moe.combine"):
+        out_flat = expert_out.reshape(G, E * C, d)
+        src = jnp.where(keep, flat_e * C + pos, 0)
+        gathered = jnp.take_along_axis(
+            out_flat, src[..., None].astype(jnp.int32), axis=1)    # (G,k*S,d)
+        w = (topv.swapaxes(1, 2).reshape(G, S * k) * keep).astype(
+            gathered.dtype)
+        y = jnp.sum((gathered * w[..., None]).reshape(G, k, S, d), axis=1)
+    with jax.named_scope("moe.route"):
+        return y, _aux_loss(gates, topi, E)
 
 
 def _moe_einsum(params, x, *, n_experts: int, k: int, capacity: int):
-    """GShard one-hot reference implementation.  x: (G,S,d)."""
+    """GShard one-hot reference implementation.  x: (G,S,d).  Stages
+    named as in :func:`_moe_gather`."""
     G, S, d = x.shape
     E, C = n_experts, capacity
-    gates, topv, topi = _route(x, params["router"], k)
+    with jax.named_scope("moe.route"):
+        gates, topv, topi = _route(x, params["router"], k)
 
-    counts = jnp.zeros((G, E), jnp.int32)
-    combine = jnp.zeros((G, S, E, C), jnp.float32)
-    for j in range(k):
-        oh = jax.nn.one_hot(topi[..., j], E, dtype=jnp.int32)      # (G,S,E)
-        prior = counts[:, None, :] + jnp.cumsum(oh, axis=1) - oh
-        pos_tok = jnp.sum(prior * oh, axis=-1)                     # (G,S)
-        keep = (pos_tok < C) & (jnp.sum(oh, -1) > 0)
-        slot_oh = jax.nn.one_hot(pos_tok, C, dtype=jnp.float32)
-        combine = combine + (oh.astype(jnp.float32)[..., None]
-                             * slot_oh[:, :, None, :]
-                             * (topv[..., j] * keep)[..., None, None])
-        counts = counts + jnp.sum(oh, axis=1)
-    dispatch = (combine > 0).astype(x.dtype)
-    expert_in = jnp.einsum("gsec,gsd->gecd", dispatch, x)
-    expert_out = _experts_apply(params, expert_in)
-    y = jnp.einsum("gsec,gecd->gsd", combine.astype(x.dtype), expert_out)
-    return y, _aux_loss(gates, topi, E)
+        counts = jnp.zeros((G, E), jnp.int32)
+        combine = jnp.zeros((G, S, E, C), jnp.float32)
+        for j in range(k):
+            oh = jax.nn.one_hot(topi[..., j], E, dtype=jnp.int32)  # (G,S,E)
+            prior = counts[:, None, :] + jnp.cumsum(oh, axis=1) - oh
+            pos_tok = jnp.sum(prior * oh, axis=-1)                 # (G,S)
+            keep = (pos_tok < C) & (jnp.sum(oh, -1) > 0)
+            slot_oh = jax.nn.one_hot(pos_tok, C, dtype=jnp.float32)
+            combine = combine + (oh.astype(jnp.float32)[..., None]
+                                 * slot_oh[:, :, None, :]
+                                 * (topv[..., j] * keep)[..., None, None])
+            counts = counts + jnp.sum(oh, axis=1)
+    with jax.named_scope("moe.dispatch"):
+        dispatch = (combine > 0).astype(x.dtype)
+        expert_in = jnp.einsum("gsec,gsd->gecd", dispatch, x)
+    with jax.named_scope("moe.experts"):
+        expert_out = _experts_apply(params, expert_in)
+    with jax.named_scope("moe.combine"):
+        y = jnp.einsum("gsec,gecd->gsd", combine.astype(x.dtype), expert_out)
+    with jax.named_scope("moe.route"):
+        return y, _aux_loss(gates, topi, E)
 
 
 def moe_ffn(params: dict, x: jax.Array, *, n_experts: int, k: int,

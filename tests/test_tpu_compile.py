@@ -13,6 +13,7 @@ only one process at a time may load the TPU library, and test workers
 import every test file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,10 +24,11 @@ from repro.configs.base import get_config
 from repro.core import make_compressor, make_plan
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.natural.kernel import natural_fused_pallas
-from repro.kernels.natural.ops import natural_reduce_pallas
+from repro.kernels.natural.ops import (_natural_reduce_pallas,
+                                       natural_reduce_pallas)
 from repro.kernels.qsgd.kernel import (qsgd_fused_pallas, qsgd_pack_pallas,
                                        qsgd_unpack_pallas)
-from repro.kernels.qsgd.ops import qsgd_reduce_pallas
+from repro.kernels.qsgd.ops import _qsgd_reduce_pallas, qsgd_reduce_pallas
 from repro.models import init_params, param_count
 
 #: clients of the smoke configuration; the reduces stack this many
@@ -192,3 +194,51 @@ def test_qsgd_reduce_allocates_no_nd_fp32_on_v5e(spec, tpu_dispatch):
     assert "tpu_custom_call" in compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < n * d * 4 // 2, (temp, n * d * 4)
+
+
+def _named_case(name, spec):
+    """The jitted function that makes kernel ``name``'s pallas_call,
+    called without its jit, on small shapes."""
+    f32, u32, i8, u8 = jnp.float32, jnp.uint32, jnp.int8, jnp.uint8
+    cases = {
+        "natural_fused_pallas": (
+            lambda x, s: natural_fused_pallas.__wrapped__(
+                x, s, interpret=False, hw_rng=True),
+            spec((64, 128), f32), spec((2,), u32)),
+        "_natural_reduce_pallas": (
+            lambda e, s: _natural_reduce_pallas.__wrapped__(
+                e, s, None, rows=32, interpret=False, has_w=False),
+            spec((2, 64, 128), u8), spec((2, 64, 16), u8)),
+        "qsgd_fused_pallas": (
+            lambda x, s: qsgd_fused_pallas.__wrapped__(
+                x, s, interpret=False, hw_rng=True),
+            spec((32, 2048), f32), spec((2,), u32)),
+        "qsgd_pack_pallas": (
+            lambda x, s: qsgd_pack_pallas.__wrapped__(
+                x, s, interpret=False, hw_rng=True),
+            spec((32, 2048), f32), spec((2,), u32)),
+        "_qsgd_reduce_pallas": (
+            lambda c, m: _qsgd_reduce_pallas.__wrapped__(
+                c, m, None, levels=127, rows=32, interpret=False,
+                has_w=False),
+            spec((2, 32, 2048), i8), spec((2, 32, 1), f32)),
+        "qsgd_unpack_pallas": (
+            lambda c, m: qsgd_unpack_pallas.__wrapped__(
+                c, m, interpret=False),
+            spec((32, 2048), i8), spec((32, 1), f32)),
+    }
+    return cases[name]
+
+
+@pytest.mark.parametrize("name", [
+    "natural_fused_pallas", "_natural_reduce_pallas", "qsgd_fused_pallas",
+    "qsgd_pack_pallas", "_qsgd_reduce_pallas", "qsgd_unpack_pallas"])
+def test_codec_kernel_op_keeps_its_name_without_its_jit(name, spec):
+    """A codec kernel's device op, the event a chip trace shows, is
+    named by its pallas_call's ``name=``: called without the jitted
+    function around it, it keeps the name the benchmark's kernel
+    readers match."""
+    fn, *args = _named_case(name, spec)
+    text = _compile(fn, *args).as_text()
+    assert re.search(rf"^\s*(ROOT )?%{re.escape(name)}(\.\d+)? = .*"
+                     r"custom-call\(", text, re.M), name
