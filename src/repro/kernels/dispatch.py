@@ -21,13 +21,14 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["on_tpu", "default_interpret", "autotune_rows",
-           "autotune_attn_blocks", "row_align", "scalar_spec"]
+__all__ = ["VMEM_BUDGET_BYTES", "on_tpu", "default_interpret",
+           "autotune_rows", "autotune_attn_blocks", "row_align",
+           "scalar_spec"]
 
 # Working VMEM budget for one pipeline stage.  Cores have ~16 MiB of VMEM;
 # we target a quarter of it so double buffering (x2) plus compiler scratch
 # still fit comfortably.
-_VMEM_BUDGET_BYTES = 4 * 1024 * 1024
+VMEM_BUDGET_BYTES = 4 * 1024 * 1024
 _ROW_ALIGN = 8  # float32 sublane count
 
 
@@ -49,7 +50,7 @@ def row_align(itemsize: int) -> int:
 
 
 def autotune_rows(n_buckets: int, row_bytes: int, *, min_itemsize: int = 4,
-                  vmem_budget: int = _VMEM_BUDGET_BYTES) -> int:
+                  vmem_budget: int = VMEM_BUDGET_BYTES) -> int:
     """Rows (buckets) per grid step so the kernel's live VMEM bytes,
     ``row_bytes`` per bucket row, fit the budget, clamped to the grid.
     Rows are aligned to the native tile of the narrowest dtype the
@@ -78,7 +79,7 @@ _ATTN_BLOCK_ALIGN = 128  # MXU tile edge; q/k blocks stay lane-aligned
 
 
 def autotune_attn_blocks(S: int, T: int, D: int, *, itemsize: int = 4,
-                         vmem_budget: int = _VMEM_BUDGET_BYTES):
+                         vmem_budget: int = VMEM_BUDGET_BYTES):
     """(bq, bk) block sizes for the flash-attention kernel so the live
     tiles — q (bq, D), k/v (bk, D), scores (bq, bk), accumulator (bq, D)
     — fit the VMEM budget, MXU-aligned (multiples of 128) and clamped to

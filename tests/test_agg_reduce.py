@@ -12,7 +12,9 @@ tree ever exists.  Pinned here:
     allclose otherwise (the fused path adds clients in index order
     0..n-1; XLA's axis-0 reduce may associate differently);
   * the Pallas reduce kernels (interpret mode) are bit-exact vs the jnp
-    scan refs, weights and no-weights, and unroll-invariant;
+    scan refs, weights and no-weights, and unroll-invariant; the
+    natural one's lane-dense sign decode at every sign byte, the edge
+    exponent codes and a ragged last tile;
   * `compressed_average` routes flat/packed plans through the fused
     engine and every other codec through the historic path bit-exactly;
   * stacked and client-sharded aggregation stay BIT-EXACT with each
@@ -113,6 +115,46 @@ def test_reduce_kernels_interpret_bit_exact(codec, weighted):
                                     unroll=1)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     np.testing.assert_array_equal(np.asarray(ref_u1), np.asarray(ref))
+
+
+def _natural_rows():
+    """Rows per tile the natural reduce picks for 128-wide buckets."""
+    from repro.kernels.natural.ops import _reduce_rows
+    return _reduce_rows(10 ** 7, 128)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3])
+@pytest.mark.parametrize("rows", ["small", "auto"])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_natural_reduce_pallas_edges_bit_exact(n, rows, weighted):
+    """The lane-dense sign decode of the natural reduce (interpret mode)
+    == the jnp scan ref at the uint32 bit level: every sign byte 0-255,
+    exponent codes 0 and 254, zero weights, and a ragged last tile both
+    at a small row count and at the one the VMEM budget picks."""
+    from repro.kernels.natural.ops import natural_reduce_pallas
+    from repro.kernels.natural.ref import natural_reduce_ref
+    r = 32 if rows == "small" else _natural_rows()
+    nb = r + r // 2 + 4                       # two tiles, the last ragged
+    rng = np.random.default_rng(n)
+    exps = rng.integers(0, 255, (n, nb, 128), dtype=np.uint8)
+    exps[:, 0, :64], exps[:, 0, 64:] = 0, 254
+    signs = rng.integers(0, 256, (n, nb, 16), dtype=np.uint8)
+    signs[:, 1:17] = np.arange(256, dtype=np.uint8).reshape(16, 16)
+    w = None
+    if weighted:
+        w = jnp.asarray([0.0, 1.5, -0.25][:n] if n > 1 else [-0.75],
+                        jnp.float32)
+    got = natural_reduce_pallas(jnp.asarray(exps), jnp.asarray(signs), w,
+                                rows=r, interpret=True)
+    ref = natural_reduce_ref(jnp.asarray(exps), jnp.asarray(signs), w)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(ref).view(np.uint32))
+
+
+def test_natural_reduce_row_budget_counts_the_lane_dense_decode():
+    """The VMEM accounting of the natural reduce buys more rows per tile
+    than the 320 the (rows, 16, 8) shift unpack left room for."""
+    assert _natural_rows() > 320
 
 
 @pytest.mark.parametrize("codec", ["identity", "qsgd", "natural",

@@ -186,15 +186,16 @@ def test_autotune_rows():
     narrowest blocked dtype (8 rows of f32, 32 of int8/uint8) and clamp
     to the grid; the natural reduce counts its own live bytes."""
     from repro.kernels.dispatch import autotune_rows
-    from repro.kernels.natural.ops import _reduce_row_bytes
+    from repro.kernels.natural.ops import _reduce_row_bytes, _reduce_rows
     budget = 4 * 1024 * 1024
     assert autotune_rows(10 ** 6, 2 * 2048 * 4) == 256
     rows = autotune_rows(10 ** 6, 3 * 2048 * 4)
     assert rows % 8 == 0 and rows * 3 * 2048 * 4 <= budget
     rows = autotune_rows(10 ** 6, 3 * 2048 * 4, min_itemsize=1)
     assert rows % 32 == 0 and rows * 3 * 2048 * 4 <= budget
-    rows = autotune_rows(10 ** 6, _reduce_row_bytes(128), min_itemsize=1)
-    assert rows % 32 == 0 and rows * _reduce_row_bytes(128) <= budget
+    # its row bytes count both pipeline buffers: two stages' budget
+    rows = _reduce_rows(10 ** 6, 128)
+    assert rows % 32 == 0 and rows * _reduce_row_bytes(128) <= 2 * budget
     assert autotune_rows(5, 2 * 128 * 4) == 5            # clamped
     assert autotune_rows(10 ** 6, budget, min_itemsize=1) == 32
 
