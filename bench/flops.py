@@ -1,37 +1,13 @@
-"""Operations and bytes the benchmark credits to the work, computed from
-shapes alone.
-
-* Model FLOPs per trained token: 6 x the matmul parameters a token goes
-  through (forward 2, backward 4) plus causal-free attention scores and
-  values (12 S H D per layer, forward and backward: the program computes
-  the full masked S x S matrix).  Recomputation under ``remat`` is not
-  counted.  The embedding lookup is not a matmul; the tied unembedding
-  is.
-* Compression kernels of one communicated round, per kernel family:
-  the bytes the kernel must read and write (HBM traffic at its roofline)
-  and its elementwise operations.  All of them are bandwidth-bound.
+"""Operations and bytes the benchmark credits to the compression
+kernels of one communicated round, per kernel family, computed from
+shapes alone: the bytes the kernel must read and write (HBM traffic at
+its roofline) and its elementwise operations.  All of them are
+bandwidth-bound.  A model's FLOPs per trained token are its own
+(``models/<model>.py``).
 """
 from __future__ import annotations
 
 import math
-
-
-def matmul_params_per_token(spec: dict) -> int:
-    d, H, K, D = spec["d_model"], spec["heads"], spec["kv_heads"], \
-        spec["head_dim"]
-    attn = d * (H + 2 * K) * D + H * D * d
-    if spec.get("experts"):
-        ffn = d * spec["experts"] + spec["experts_per_token"] * 3 * d \
-            * spec["expert_width"]
-    else:
-        ffn = 3 * d * spec["d_ff"]
-    return spec["layers"] * (attn + ffn) + spec["vocab"] * d
-
-
-def train_flops_per_token(spec: dict, seq: int) -> float:
-    attn_scores = 12 * seq * spec["heads"] * spec["head_dim"] \
-        * spec["layers"]
-    return 6.0 * matmul_params_per_token(spec) + attn_scores
 
 
 def padded(n: int, bucket: int) -> int:

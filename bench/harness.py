@@ -9,7 +9,16 @@ Everything is found by file name under ``bench/``:
   traffic parameters and why it exists;
 * ``kinds/<kind>.py``         — the driver of one kind of cell;
 * ``metrics/<metric>.py``     — a per-layer metric reader, ``read(rec)``
-  returning a number or ``None`` where the run has nothing to read.
+  returning a number or ``None`` where the run has nothing to read;
+* ``models/<model>.py``       — the model a configuration names under
+  ``"model"``, as five functions: ``program_config(spec)``, the
+  configuration's keys mapped to the program's ``ArchConfig``;
+  ``reference_spec(spec)``, the widths the reference and the FLOP count
+  read; ``loss(params, tokens, rspec, dtype=jnp.float32)``, the plain
+  reference's loss, from a file under ``reference/`` that imports
+  nothing of the program; ``train_flops_per_token(rspec, seq)``; and
+  ``tiny(spec)``, the keys to change for the CPU size the tests run a
+  cell at.
 
 ``BENCHMARK.json`` at the checkout's root says which metrics each cell
 reports.
@@ -33,10 +42,11 @@ def _json(path: str) -> dict:
 
 def load_cell(name: str, bench_dir: str = None) -> dict:
     """The cell file with its configuration file loaded under
-    ``"config_spec"``."""
+    ``"config_spec"``, and the directory it was found in under
+    ``"bench_dir"``."""
     bench_dir = bench_dir or BENCH_DIR
     cell = _json(os.path.join(bench_dir, "workloads", f"{name}.json"))
-    cell["name"] = name
+    cell["name"], cell["bench_dir"] = name, bench_dir
     cell["config_spec"] = _json(os.path.join(bench_dir, "configs",
                                              f"{cell['config']}.json"))
     return cell
@@ -60,14 +70,23 @@ def metrics_of(spec: dict, cell: str, section: str) -> list:
                 else m["moves"] in names)]
 
 
-def reader(metric: str, bench_dir: str = None):
-    """The ``read`` function of ``metrics/<metric>.py``."""
-    path = os.path.join(bench_dir or BENCH_DIR, "metrics", f"{metric}.py")
-    mod_name = "bench_metric_" + metric.replace(".", "_").replace("-", "_")
+def _load(bench_dir: str, sub: str, name: str, prefix: str):
+    path = os.path.join(bench_dir or BENCH_DIR, sub, f"{name}.py")
+    mod_name = prefix + name.replace(".", "_").replace("-", "_")
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str, bench_dir: str = None):
+    """The ``read`` function of ``metrics/<metric>.py``."""
+    return _load(bench_dir, "metrics", metric, "bench_metric_").read
+
+
+def model_of(config_spec: dict, bench_dir: str = None):
+    """The module ``models/<model>.py`` of a configuration's ``"model"``."""
+    return _load(bench_dir, "models", config_spec["model"], "bench_model_")
 
 
 def require_chips(count: int) -> dict:

@@ -4,6 +4,11 @@ driver runs it (``repro.fl.l2gd_driver``'s scan mode): stack the
 chunk's batches, dispatch one jitted ``rollout_l2gd`` chunk, fetch the
 trace buffers, replay the xi trace into the program's ``BitsLedger``.
 
+The configuration's ``"model"`` key names the module
+``bench/models/<model>.py`` that maps its keys to the program's
+``ArchConfig`` and gives its plain reference and FLOP count; nothing
+here reads a configuration key.
+
 Set-up builds that one jitted chunk, runs the first chunk from the
 seed's weights (it compiles, or loads from the compile cache) and one
 more to warm, and hands the same object and state to the window.  The
@@ -43,7 +48,6 @@ target's error off the mean's: both rounds are covered.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 import gc
 import os
@@ -54,61 +58,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import flops, harness, peaks, weights
+from bench import harness, peaks, weights
 from bench import trace as trace_lib
-from bench.reference import lm as ref_lm
 from bench.reference import protocol as ref_protocol
 from bench.traffic.tokens import TokenStream
 
-from repro.configs.base import get_config
 from repro.core import L2GDHyper, init_state, make_compressor
 from repro.core.codec import make_plan
 from repro.core.rollout import rollout_l2gd
 from repro.fl.ledger import BitsLedger
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, loss_fn
-
-#: configuration-file keys (Hugging Face names) -> the program's fields
-_KEYS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
-         "num_attention_heads": "n_heads",
-         "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-         "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-         "rms_norm_eps": "norm_eps"}
-_MOE_KEYS = {"num_local_experts": "n_experts",
-             "num_experts_per_tok": "experts_per_token",
-             "intermediate_size": "moe_d_ff",
-             "capacity_factor": "capacity_factor",
-             "router_aux_loss_coef": "aux_loss_weight"}
-
-
-def program_config(spec: dict):
-    """The program's ArchConfig for a configuration file."""
-    ch = {field: spec[key] for key, field in _KEYS.items()}
-    ch["d_ff"] = spec["intermediate_size"]
-    if spec.get("num_local_experts"):
-        ch.update({field: spec[key] for key, field in _MOE_KEYS.items()})
-    ch["param_dtype"] = ch["compute_dtype"] = spec["dtype"]
-    return dataclasses.replace(get_config(spec["program_arch"]), **ch)
-
-
-def reference_spec(spec: dict) -> dict:
-    """The widths the reference and the FLOP counts read."""
-    out = {"d_model": spec["hidden_size"],
-           "layers": spec["num_hidden_layers"],
-           "heads": spec["num_attention_heads"],
-           "kv_heads": spec["num_key_value_heads"],
-           "head_dim": spec["head_dim"], "vocab": spec["vocab_size"],
-           "d_ff": spec["intermediate_size"],
-           "rope_theta": float(spec["rope_theta"]),
-           "norm_eps": float(spec["rms_norm_eps"])}
-    if spec.get("num_local_experts"):
-        out.update(experts=spec["num_local_experts"],
-                   experts_per_token=spec["num_experts_per_tok"],
-                   expert_width=spec["intermediate_size"],
-                   capacity_factor=float(spec["capacity_factor"]),
-                   aux_loss_weight=float(spec["router_aux_loss_coef"]))
-    return out
-
 
 # ---------------------------------------------------------------------------
 # the program under test (module attributes, so that a test can plant a
@@ -179,7 +139,10 @@ class Job:
     def __init__(self, cell: dict, seed: int, roll=None):
         self.cell, self.seed = cell, int(seed)
         P = self.P = cell["params"]
-        self.cfg = program_config(cell["config_spec"])
+        self.model = harness.model_of(cell["config_spec"],
+                                      cell["bench_dir"])
+        self.cfg = self.model.program_config(cell["config_spec"])
+        self.rspec = self.model.reference_spec(cell["config_spec"])
         self.n, self.chunk_len = P["clients"], P["chunk"]
         self.shapes = jax.eval_shape(
             lambda: init_params(jax.random.PRNGKey(0), self.cfg))
@@ -273,11 +236,11 @@ class Job:
                        "agg_err": e, "agg_corr": corr}
 
 
-def _reference_step(spec):
+def _reference_step(loss, spec):
     @jax.jit
     def step(x, tokens):
         with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(ref_lm.loss)(x, tokens, spec)
+            return jax.value_and_grad(loss)(x, tokens, spec)
     return step
 
 
@@ -291,12 +254,11 @@ def reference_readings(job: Job) -> dict:
     the same weights and batches, the loss before each of them and
     before the fresh round, each leaf's change and first gradient, and
     its own compressed target of its own mean."""
-    spec = reference_spec(job.cell["config_spec"])
     P, n, L = job.P, job.n, job.chunk_len
     f32_shapes = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), job.shapes)
     x0 = weights.make_weights(job.seed, f32_shapes, n)
-    step = _reference_step(spec)
+    step = _reference_step(job.model.loss, job.rspec)
     lr = P["eta"] / (n * (1.0 - P["p"]))
     losses = np.zeros((L - 1, n))
     deltas, grads0, flats = [], [], []
@@ -408,12 +370,11 @@ def run(cell: dict, args, t0: float, device: dict, spec_b=None) -> tuple:
 
     window_s = t_end - t_start
     tokens = local * P["local_steps"] * job.n * P["batch"] * P["seq"]
-    spec = reference_spec(cell["config_spec"])
     rec = {"kind": "train", "cell": cell["name"], "params": P,
            "chips": cell["chips"], "window_s": window_s, "steps": steps,
            "local_steps": local, "comm_rounds": comm, "tokens": tokens,
-           "model_flops": tokens * flops.train_flops_per_token(
-               spec, P["seq"]),
+           "model_flops": tokens * job.model.train_flops_per_token(
+               job.rspec, P["seq"]),
            "peaks": peaks.peaks_for(device["kind"]),
            "flat_size": job.d,
            "compiles_in_window": counter.count}

@@ -1,6 +1,7 @@
 """Model FLOP utilization of the traced window: the forward and
-backward FLOPs of the local gradient steps completed in it (bench/flops,
-recomputation not counted) over window x chips x the chip's peak."""
+backward FLOPs of the local gradient steps completed in it (the
+configuration's ``bench/models/<model>.py``, recomputation not counted)
+over window x chips x the chip's peak."""
 
 
 def read(rec):

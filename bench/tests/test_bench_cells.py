@@ -3,6 +3,7 @@ generator and window run end to end at a tiny size on the CPU; and
 ``correct`` comes out false for the lower-precision control and for
 each planted fault, under the cells' own limits."""
 import copy
+import importlib
 import json
 import os
 import sys
@@ -22,6 +23,9 @@ CELLS = [w["name"] for w in SPEC["workloads"]]
 #: protocol keys whose first 4-step chunk is 0, 0, 1, 1 at p = 0.5 / 0.1
 TINY_KEYS = {0.5: 2, 0.1: 408}
 DEVICE = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
+#: what every ``bench/models/<model>.py`` gives
+MODEL_FUNCTIONS = ("program_config", "reference_spec", "loss",
+                   "train_flops_per_token", "tiny")
 
 
 def test_every_cell_and_metric_of_the_benchmark_has_its_files():
@@ -31,7 +35,11 @@ def test_every_cell_and_metric_of_the_benchmark_has_its_files():
         assert cell["why"] == w["why"]
     for c in SPEC["configs"]:
         with open(os.path.join(ROOT, c["file"])) as f:
-            assert json.load(f)["reduced"] == c["reduced"]
+            config_spec = json.load(f)
+        assert config_spec["reduced"] == c["reduced"]
+        model = harness.model_of(config_spec)
+        for fn in MODEL_FUNCTIONS:
+            assert callable(getattr(model, fn)), (c["name"], fn)
     for m in SPEC["per_layer"]:
         assert callable(harness.reader(m["name"]))
 
@@ -60,18 +68,13 @@ def test_a_new_cell_and_metric_are_found_by_their_files(tmp_path):
         spec, "m.new-mix", "per_layer")] == ["twice.train", "all.train"]
 
 
-def tiny(name: str) -> dict:
-    """The cell at a CPU size: widths, batch, sequence and chunk cut,
-    everything else (codecs, p, limits) as the cell states it."""
-    cell = copy.deepcopy(harness.load_cell(name))
+def tiny(name: str, bench_dir: str = None) -> dict:
+    """The cell at a CPU size: widths (its model's ``tiny``), batch,
+    sequence and chunk cut, everything else (codecs, p, limits) as the
+    cell states it."""
+    cell = copy.deepcopy(harness.load_cell(name, bench_dir))
     spec = cell["config_spec"]
-    # about 0.6M elements per client: enough for the aggregation
-    # statistics (in units of 1/sqrt(d)) to tell a planted fault apart
-    spec.update(hidden_size=128, intermediate_size=192, num_hidden_layers=2,
-                num_attention_heads=4, num_key_value_heads=2, head_dim=32,
-                vocab_size=4096)
-    if spec.get("num_local_experts"):
-        spec.update(num_local_experts=4, num_experts_per_tok=2)
+    spec.update(harness.model_of(spec, bench_dir).tiny(spec))
     P = cell["params"]
     P.update(batch=2, seq=16, chunk=4,
              protocol_key=TINY_KEYS[P["p"]])
@@ -79,8 +82,8 @@ def tiny(name: str) -> dict:
 
 
 def run_cell(cell, seconds=0.3, seed=2 ** 31 + 7):
-    from bench.kinds import train
-    return train.run(cell, types.SimpleNamespace(
+    kind = importlib.import_module(f"bench.kinds.{cell['kind']}")
+    return kind.run(cell, types.SimpleNamespace(
         seed=seed, seconds=seconds, trace=0), time.time(), DEVICE, SPEC)
 
 
@@ -98,6 +101,41 @@ def test_tiny_cell_runs_whole_chunks_and_is_correct(name):
     assert result["failed"] == 0
     assert set(result["metrics"]) == {"train_tokens_per_s", "setup_s"}
     assert 0 < result["metrics"]["setup_s"]["value"] < time.time() - t
+    assert result["metrics"]["train_tokens_per_s"]["value"] > 0
+
+
+def test_a_new_model_is_found_by_its_files(tmp_path):
+    """A model module, a configuration and a cell, written as new files
+    into a bench directory of their own, run to ``correct`` with no edit
+    to any file the benchmark has: the harness finds the model by the
+    configuration's ``"model"`` key, which names no module under
+    ``bench/models``."""
+    for d in ("models", "configs", "workloads"):
+        (tmp_path / d).mkdir()
+    (tmp_path / "models" / "decoder_copy.py").write_text(
+        "from bench.models.decoder import (  # noqa: F401\n"
+        "    program_config, reference_spec, train_flops_per_token, tiny)\n"
+        "from bench.reference.lm import loss  # noqa: F401\n")
+    assert not os.path.exists(os.path.join(harness.BENCH_DIR, "models",
+                                           "decoder_copy.py"))
+    src = harness.load_cell(CELLS[0])
+    config_spec = dict(src["config_spec"], name="new-model",
+                       model="decoder_copy")
+    (tmp_path / "configs" / "new-model.json").write_text(
+        json.dumps(config_spec))
+    with open(os.path.join(harness.BENCH_DIR, "workloads",
+                           f"{CELLS[0]}.json")) as f:
+        cell_file = dict(json.load(f), config="new-model")
+    (tmp_path / "workloads" / "new-model.mix.json").write_text(
+        json.dumps(cell_file))
+
+    cell = tiny("new-model.mix", bench_dir=str(tmp_path))
+    model = harness.model_of(cell["config_spec"], str(tmp_path))
+    assert os.path.dirname(model.__file__) == str(tmp_path / "models")
+    for fn in MODEL_FUNCTIONS:
+        assert callable(getattr(model, fn)), fn
+    result, checks = run_cell(cell, seconds=0.1)
+    assert correct(result, checks), checks
     assert result["metrics"]["train_tokens_per_s"]["value"] > 0
 
 

@@ -5,11 +5,13 @@ import sys
 
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from bench import flops, harness, peaks  # noqa: E402
 from bench import trace as tl  # noqa: E402
+from bench.models import decoder  # noqa: E402
 
 MS = 1_000_000
 
@@ -67,12 +69,12 @@ def test_train_flops_per_token_dense_and_moe():
     dense = {"d_model": 4, "heads": 2, "kv_heads": 1, "head_dim": 2,
              "d_ff": 8, "layers": 3, "vocab": 10}
     # attn 4*(2+2)*2 + 2*2*4 = 48, ffn 3*4*8 = 96, unembed 40
-    assert flops.matmul_params_per_token(dense) == 3 * (48 + 96) + 40
-    assert flops.train_flops_per_token(dense, 5) == \
+    assert decoder.matmul_params_per_token(dense) == 3 * (48 + 96) + 40
+    assert decoder.train_flops_per_token(dense, 5) == \
         6.0 * (3 * 144 + 40) + 12 * 5 * 2 * 2 * 3
     moe = dict(dense, experts=4, experts_per_token=2, expert_width=3)
     # ffn: router 4*4 + 2 * 3 * 4 * 3
-    assert flops.matmul_params_per_token(moe) == 3 * (48 + 16 + 72) + 40
+    assert decoder.matmul_params_per_token(moe) == 3 * (48 + 16 + 72) + 40
 
 
 def test_round_kernel_bytes():
