@@ -4,6 +4,14 @@ driver runs it (``repro.fl.l2gd_driver``'s scan mode): stack the
 chunk's batches, dispatch one jitted ``rollout_l2gd`` chunk, fetch the
 trace buffers, replay the xi trace into the program's ``BitsLedger``.
 
+A cell on more than one chip runs the client-sharded engine instead,
+as ``repro.launch.steps.build_sharded_rollout_fn`` jits it: the clients
+are sharded over a ``clients`` mesh of the cell's chips (a whole number
+of clients per chip), the state is donated to each chunk, and a fresh
+round's uplink payloads cross the chips in one ``all_gather``.  The
+weights are made in that layout and each chunk's batches go straight to
+their shards, so no device ever holds every client.
+
 The configuration's ``"model"`` key names the module
 ``bench/models/<model>.py`` that maps its keys to the program's
 ``ArchConfig`` and gives its plain reference and FLOP count; nothing
@@ -65,9 +73,12 @@ from bench.traffic.tokens import TokenStream
 
 from repro.core import L2GDHyper, init_state, make_compressor
 from repro.core.codec import make_plan
-from repro.core.rollout import rollout_l2gd
+from repro.core.rollout import rollout_l2gd, rollout_l2gd_sharded
 from repro.fl.ledger import BitsLedger
 from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_client_mesh
+from repro.launch.sharding import (client_sharded_batch_shardings,
+                                   client_sharded_shardings)
 from repro.models import init_params, loss_fn
 
 # ---------------------------------------------------------------------------
@@ -91,6 +102,16 @@ def program_chunk(grad_fn, up_plan, down_plan, length: int,
         rollout_l2gd, grad_fn=grad_fn, steps=length, client_comp=up_plan,
         master_comp=down_plan, batch_axis=0, participation=None,
         local_steps=local_steps))
+
+
+def program_sharded_chunk(grad_fn, up_plan, down_plan, length: int,
+                          local_steps: int, mesh):
+    """One scanned chunk of the client-sharded engine on ``mesh``, jitted
+    with the state donated, as ``build_sharded_rollout_fn`` jits it."""
+    return jax.jit(functools.partial(
+        rollout_l2gd_sharded, mesh=mesh, grad_fn=grad_fn, steps=length,
+        client_comp=up_plan, master_comp=down_plan, batch_axis=0,
+        participation=None, local_steps=local_steps), donate_argnums=(1,))
 
 
 def codec(c: dict):
@@ -134,11 +155,17 @@ def _stats(sq, mm, em):
 
 class Job:
     """The cell's program objects for one seed: configuration, weights,
-    traffic, plans and the one jitted chunk."""
+    traffic, plans, the client mesh of a multi-chip cell and the one
+    jitted chunk."""
 
     def __init__(self, cell: dict, seed: int, roll=None):
         self.cell, self.seed = cell, int(seed)
         P = self.P = cell["params"]
+        chips = cell["chips"]
+        if P["clients"] % chips:
+            raise ValueError(f"{P['clients']} clients do not divide over "
+                             f"{chips} chips")
+        self.mesh = make_client_mesh(chips) if chips > 1 else None
         self.model = harness.model_of(cell["config_spec"],
                                       cell["bench_dir"])
         self.cfg = self.model.program_config(cell["config_spec"])
@@ -159,9 +186,11 @@ class Job:
                             transport=P["transport"])
         self.down = make_plan(codec(P["downlink"]), one,
                               transport=P["transport"])
-        self.roll = roll or program_chunk(
-            program_grad_fn(self.cfg), self.up, self.down, self.chunk_len,
-            P["local_steps"])
+        build = program_chunk if self.mesh is None else functools.partial(
+            program_sharded_chunk, mesh=self.mesh)
+        self.roll = roll or build(program_grad_fn(self.cfg), self.up,
+                                  self.down, self.chunk_len,
+                                  P["local_steps"])
         self.key = jax.random.PRNGKey(P["protocol_key"])
         self.ledger = BitsLedger(self.n)
         self.up_bits, self.down_bits = self.up.round_bits(), \
@@ -182,14 +211,33 @@ class Job:
     def fill(self, chunks: int) -> None:
         self.batch_at(chunks * self.chunk_len - 1)
 
+    def device_of(self, client: int):
+        """The device that holds ``client``."""
+        if self.mesh is None:
+            return jax.devices()[0]
+        return self.mesh.devices.flat[client * self.mesh.size // self.n]
+
+    def weights(self):
+        """The seed's stacked weights, in the chunk's layout."""
+        return weights.make_weights(self.seed, self.shapes, self.n,
+                                    self.mesh)
+
+    def stack(self, c0: int):
+        """The chunk's (steps, clients, batch, seq) tokens on the
+        device, or straight to their shards on a client mesh."""
+        steps = [self.batch_at(c0 + i) for i in range(self.chunk_len)]
+        if self.mesh is None:
+            return {"tokens": jnp.stack([jnp.asarray(b) for b in steps])}
+        batches = {"tokens": np.stack(steps)}
+        return jax.device_put(batches, client_sharded_batch_shardings(
+            self.mesh, batches))
+
     def chunk(self, state):
         """One chunk of the program's driver loop."""
         c0 = self.done
         ts = [time.perf_counter()]
         with harness.span("bench.stack"):
-            batches = {"tokens": jnp.stack(
-                [jnp.asarray(self.batch_at(c0 + i))
-                 for i in range(self.chunk_len)])}
+            batches = self.stack(c0)
         ts.append(time.perf_counter())
         with harness.span("bench.dispatch"):
             state, tr = self.roll(self.key, state, self.hp, batches, None)
@@ -220,17 +268,21 @@ class Job:
 
     def first_chunk(self):
         """Set-up's first chunk from the seed's weights, and the
-        program-side readings of it (no reference yet)."""
-        x0 = weights.make_weights(self.seed, self.shapes, self.n)
-        state0 = init_state(x0)
+        program-side readings of it (no reference yet).  A donated chunk
+        consumes its state, so the readings take the weights made again
+        from the seed."""
+        state0 = init_state(self.weights())
+        if self.mesh is not None:
+            state0 = jax.device_put(
+                state0, client_sharded_shardings(self.mesh, state0))
         state, xis, losses, _ = self.chunk(state0)
+        del state0
         expected = np.array([0] * (self.chunk_len - 2) + [1, 1])
         self.mismatches += int(not np.array_equal(xis, expected))
         c = float(self.P["eta"] * self.P["lam"] / (self.n * self.P["p"]))
-        deltas, sq, mm, em = _program_readings(state0.params, state.params,
+        deltas, sq, mm, em = _program_readings(self.weights(), state.params,
                                                state.cache, c)
         e, corr = _stats(float(sq), float(mm), float(em))
-        del state0, x0
         return state, {"losses": np.asarray(losses[:-1], np.float64),
                        "deltas": np.asarray(deltas, np.float64),
                        "agg_err": e, "agg_corr": corr}
@@ -253,20 +305,21 @@ def reference_readings(job: Job) -> dict:
     """The reference's first chunk: its 14 local steps per client from
     the same weights and batches, the loss before each of them and
     before the fresh round, each leaf's change and first gradient, and
-    its own compressed target of its own mean."""
+    its own compressed target of its own mean.  Each client runs on the
+    device that holds it in the program, from its own weights alone."""
     P, n, L = job.P, job.n, job.chunk_len
     f32_shapes = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), job.shapes)
-    x0 = weights.make_weights(job.seed, f32_shapes, n)
     step = _reference_step(job.model.loss, job.rspec)
     lr = P["eta"] / (n * (1.0 - P["p"]))
     losses = np.zeros((L - 1, n))
     deltas, grads0, flats = [], [], []
     for i in range(n):
-        xi0 = jax.tree.map(lambda a: a[i], x0)
+        dev = job.device_of(i)
+        xi0 = weights.make_client_weights(job.seed, f32_shapes, i, dev)
         x = xi0
         for k in range(L - 1):
-            loss, g = step(x, jnp.asarray(job.batch_at(k)[i]))
+            loss, g = step(x, jax.device_put(job.batch_at(k)[i], dev))
             losses[k, i] = float(loss)
             if k == 0:
                 grads0.append(_leaf_norms(g))
@@ -277,9 +330,7 @@ def reference_readings(job: Job) -> dict:
         flats.append(jnp.concatenate(
             [l.reshape(-1) for l in jax.tree_util.tree_leaves(x)]))
         del x, xi0
-    del x0
-    flats = jnp.stack(flats)
-    m = jnp.mean(flats, axis=0)
+    m = ref_protocol.mean(flats)
     t = ref_protocol.target(
         jax.random.fold_in(weights.seed_key(job.seed), 0x7EF),
         flats, P["uplink"], P["downlink"])

@@ -15,7 +15,9 @@ Variants:
   halfbatch  the local gradient taken over half of each client's batch;
   altered    the aggregation target altered where it is produced
              (scaled by 1 + 1/16);
-  cached     a round on the cached target leaves the state as it was.
+  cached     a round on the cached target leaves the state as it was;
+  nogather   the uplink all_gather left out: each chip averages only
+             the clients it holds (cells on more than one chip).
 ``--protocol-key`` reads under another key whose first chunk has the
 same branches (a witness for a reading that a fixed key may shift).
 One JSON line per (variant, seed) goes to ``--out``.
@@ -36,28 +38,43 @@ sys.path[:0] = [_ROOT, os.path.join(_ROOT, "src")]
 from bench import harness  # noqa: E402
 
 VARIANTS = ("sound", "control", "unchanged", "halfbatch", "altered",
-            "cached")
+            "cached", "nogather")
+#: the faults a cell can have: on one chip there is no exchange to leave
+#: out
+FAULTS = VARIANTS[2:]
+ONE_CHIP_FAULTS = FAULTS[:-1]
+
+
+def faults_of(cell: dict) -> tuple:
+    return FAULTS if cell["chips"] > 1 else ONE_CHIP_FAULTS
 
 
 def planted(fault: str, train) -> list:
     """[(owner, attribute, replacement)] that plant ``fault`` under a
-    whole run of a train cell (the CPU tests plant them the same way)."""
+    whole run of a train cell, on one chip or on a client mesh (the CPU
+    tests plant them the same way)."""
     import jax
     import jax.numpy as jnp
-    from repro.core import rollout
+    from repro.core import aggregation, rollout
     from repro.core.aggregation import compressed_average
 
     if fault == "unchanged":
-        real = train.program_chunk
+        def unchanged(real, donated: bool):
+            def chunk(*a, **kw):
+                roll = real(*a, **kw)
 
-        def chunk(*a, **kw):
-            roll = real(*a, **kw)
-
-            def broken(key, state, hp, batches, forced):
-                _, tr = roll(key, state, hp, batches, forced)
-                return state, tr
-            return broken
-        return [(train, "program_chunk", chunk)]
+                def broken(key, state, hp, batches, forced):
+                    # a donated chunk consumes the state it is given
+                    kept = jax.tree.map(jnp.copy, state) if donated \
+                        else state
+                    _, tr = roll(key, state, hp, batches, forced)
+                    return kept, tr
+                return broken
+            return chunk
+        return [(train, "program_chunk",
+                 unchanged(train.program_chunk, False)),
+                (train, "program_sharded_chunk",
+                 unchanged(train.program_sharded_chunk, True))]
     if fault == "halfbatch":
         real_grad = train.program_grad_fn
 
@@ -70,15 +87,23 @@ def planted(fault: str, train) -> list:
             return half
         return [(train, "program_grad_fn", grad_fn_of)]
     if fault == "altered":
+        def alter(t):
+            return jax.tree.map(lambda a: a * (1.0 + 1.0 / 16), t)
+
         def chunk(grad_fn, up, down, length, local_steps):
             def avg(k, params):
-                t = compressed_average(k, params, up, down)
-                return jax.tree.map(lambda a: a * (1.0 + 1.0 / 16), t)
+                return alter(compressed_average(k, params, up, down))
             return jax.jit(functools.partial(
                 rollout.rollout_l2gd, grad_fn=grad_fn, steps=length,
                 client_comp=up, master_comp=down, batch_axis=0,
                 average_fn=avg, local_steps=local_steps))
-        return [(train, "program_chunk", chunk)]
+        real_sharded = rollout.make_client_sharded_average
+
+        def sharded_average(*a, **kw):
+            avg = real_sharded(*a, **kw)
+            return lambda *b, **c: alter(avg(*b, **c))
+        return [(train, "program_chunk", chunk),
+                (rollout, "make_client_sharded_average", sharded_average)]
     if fault == "cached":
         real_step = rollout.l2gd_step
 
@@ -89,6 +114,11 @@ def planted(fault: str, train) -> list:
             return jax.tree.map(lambda o, x: jnp.where(skip, o, x),
                                 kept, new), m
         return [(rollout, "l2gd_step", step)]
+    if fault == "nogather":
+        # the client-sharded average gathers one payload per local client
+        # (batched): without the gather they are this chip's alone
+        return [(aggregation, "_gather_payloads",
+                 lambda payload, axes, *, batched: payload)]
     raise ValueError(f"unknown fault {fault!r}")
 
 

@@ -47,12 +47,23 @@ def compress(codec: dict, key, flat):
     raise ValueError(f"no reference for codec {codec['name']!r}")
 
 
+def mean(flats):
+    """mean_i x_i of the clients' flat models (a sequence of (d,)
+    arrays, each on any device), formed on the first one's device."""
+    home = flats[0].sharding
+    return sum(jax.device_put(f, home) for f in flats) / len(flats)
+
+
 def target(key, flats, uplink: dict, downlink: dict):
-    """t = C_M(mean_i C_i(x_i)) over the stacked flat models (n, d)."""
-    n = flats.shape[0]
+    """t = C_M(mean_i C_i(x_i)) over the clients' flat models (as in
+    :func:`mean`): each C_i on its client's device, the mean and C_M on
+    the first one's."""
+    n, home = len(flats), flats[0].sharding
     keys = jax.random.split(key, n + 1)
-    up = sum(compress(uplink, keys[i], flats[i]) for i in range(n)) / n
-    return compress(downlink, keys[n], up)
+    up = sum(jax.device_put(
+        compress(uplink, jax.device_put(keys[i], f.sharding), f), home)
+        for i, f in enumerate(flats)) / n
+    return compress(downlink, jax.device_put(keys[n], home), up)
 
 
 def target_stats(t, m):

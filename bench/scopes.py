@@ -28,10 +28,10 @@ UNSCOPED = ""
 #: under none of them is unscoped
 TOP_SCOPES = ("l2gd.local", "l2gd.agg_fresh", "l2gd.agg_cached",
               "rollout.streams")
-#: components of an op_name that JAX itself adds around control flow and
-#: rematerialization, never named scopes
+#: components of an op_name that JAX itself adds around control flow,
+#: rematerialization and a program mapped over a mesh, never named scopes
 _JAX_PARTS = frozenset({"while", "body", "cond", "closed_call", "checkpoint",
-                        "rematted_computation"})
+                        "rematted_computation", "shard_map"})
 #: a transformation's wrapper around the scopes inside it, as in
 #: ``vmap(transpose(jvp(model.ffn)))``
 _WRAPPER = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
@@ -199,7 +199,8 @@ def program_op_scopes(events, texts) -> dict:
     return op_scopes(best)
 
 
-_LAST = [None, None]   # [the trace reduction read last, its scope times]
+_LAST = [None, None, None]  # [the trace reduction read last, its scope
+#                              times, its ops' scope paths]
 
 
 def scope_times(rec: dict):
@@ -217,5 +218,17 @@ def scope_times(rec: dict):
         if out:
             print(f"{scope_summary(out, red['busy_s'])}; read in "
                   f"{time.perf_counter() - t0:.2f} s", flush=True)
-        _LAST[:] = [red, out]
+        _LAST[:] = [red, out, sc]
     return _LAST[1]
+
+
+def device_scope_times(rec: dict):
+    """[{scope path: device self seconds}] of each device the window
+    traced, by the scope paths of the program :func:`scope_times`
+    reads (a program across chips runs the same ops on each), or None
+    where that reads nothing."""
+    if scope_times(rec) is None:
+        return None
+    red = rec["trace"]
+    return [scope_self_times(evs, _LAST[2])
+            for evs in red.get("device_events", [red["events"]])]

@@ -177,6 +177,36 @@ def test_scope_readers_read_nothing_without_their_steps(scoped):
         _rec(local_steps=0)) is None
 
 
+def test_the_collective_reader_averages_gather_time_over_chips_per_round(
+        monkeypatch):
+    """``collective_ms_per_round.train``: each device's self time under
+    the ``gather`` scope (the shard_map's own name left out of the
+    path), averaged over the devices, per communicated round; nothing
+    where the program has no such scope or the run no rounds."""
+    read = harness.reader("collective_ms_per_round.train")
+    gathered = HLO.replace(
+        "branch_1_fun/l2gd.agg_fresh/average/uplink_encode/",
+        "branch_1_fun/l2gd.agg_fresh/average/gather/").replace(
+        "jit(roll)/while", "jit(roll)/shard_map/while")
+    monkeypatch.setattr(sc, "loaded_hlo_texts", lambda: [gathered])
+
+    def dev(gather_ms):
+        return [("%fusion.1 = f32[8] fusion(...)", 0, 30 * MS),
+                ("%natural_fused_pallas.7 = f32[8] custom-call(...)",
+                 40 * MS, gather_ms * MS)]
+    rec = _rec(trace={"busy_s": 0.04, "events": dev(8),
+                      "device_events": [dev(8), dev(12), dev(6), dev(10)]})
+    assert read(rec) == pytest.approx(9.0 / 4)
+    assert "l2gd.agg_fresh/average/gather/natural_fused_pallas" in \
+        sc.scope_times(rec)
+    assert read(_rec(trace=rec["trace"], comm_rounds=0)) is None
+    assert read(_rec(trace=None)) is None
+    # a one-chip program has no gather scope: nothing, never a zero
+    monkeypatch.setattr(sc, "loaded_hlo_texts", lambda: [HLO])
+    assert read(_rec(trace={"busy_s": 0.04, "events": dev(8),
+                            "device_events": [dev(8)]})) is None
+
+
 def test_scope_times_pick_the_program_that_ran_and_print_once(
         monkeypatch, capsys):
     other = HLO.replace("%fusion.1 =", "%fusion.7 =")

@@ -150,8 +150,9 @@ def window(spans, name: str = "bench.window"):
 
 def reduce(path: str) -> dict:
     """Everything the metric readers take from one trace: the window's
-    seconds, busy seconds averaged over the devices, and the first
-    device's events, top ops by self time and idle gaps."""
+    seconds, busy seconds averaged over the devices, each device's
+    events, and the first device's events, top ops by self time and
+    idle gaps."""
     data = load(path)
     if not data["devices"]:
         raise ValueError(f"no TPU device plane with an {OPS_LINE!r} line "
@@ -167,6 +168,7 @@ def reduce(path: str) -> dict:
         "window_s": (hi - lo) * 1e-9,
         "busy_s": sum(v["busy_s"] for v in per_dev.values()) / len(per_dev),
         "events": per_dev[first]["events"],
+        "device_events": [per_dev[d]["events"] for d in sorted(per_dev)],
         "device_ops": top_ops(per_dev[first]["events"]),
         "idle_gaps": idle_breakdown(per_dev[first]["events"],
                                     data["spans"], lo, hi),
